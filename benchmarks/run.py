@@ -1398,6 +1398,9 @@ def main() -> None:
             print(f"{name}{' *' if name in QUICK_BENCHES else ''}")
         return
     QUICK = args.quick
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.only and args.only not in BENCHES:
         ap.error(f"unknown benchmark {args.only!r}; choose from: "
                  + ", ".join(BENCHES))

@@ -32,7 +32,9 @@ Fault taxonomy (``FaultEvent.kind``):
                             raises ``KernelDispatchError``; sticky
                             ``BackendHealth`` walks the degradation ladder
                             (pallas → ref → numpy) and speculation is
-                            invalidated at the fault epoch
+                            invalidated at the fault epoch.  Only this
+                            injected fault walks the ladder: a real device
+                            error raises and ends the run
 ``scheduler_crash``         the scheduler process dies mid-run; the
                             simulator restores the latest checkpoint and
                             replays (requires a ``CheckpointStore``)

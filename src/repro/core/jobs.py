@@ -48,6 +48,12 @@ from .types import OVERLAP_EPS, TIME_EPS, JobSpec, JobState, Variant, Window
 __all__ = ["JobAgent", "AgentConfig"]
 
 
+def _unit(x: float) -> float:
+    """``float(np.clip(x, 0.0, 1.0))`` for a scalar, without numpy dispatch:
+    the bid path clips several features of every variant it builds."""
+    return min(max(float(x), 0.0), 1.0)
+
+
 @dataclass(frozen=True)
 class AgentConfig:
     theta: float = 0.05  # θ: capacity-violation risk bound
@@ -279,7 +285,7 @@ class JobAgent:
         for (misreport × shade, clipped) and the θ it bids under."""
         feats = self._features(plan.work, plan.duration, t_start, now)
         declared = {
-            k: float(np.clip(v * self.cfg.misreport * shade, 0.0, 1.0))
+            k: _unit(v * self.cfg.misreport * shade)
             for k, v in feats.items()
         }
         h = sum(self.cfg.alphas.get(k, 0.0) * v for k, v in declared.items())
@@ -295,7 +301,7 @@ class JobAgent:
             t_start=t_start,
             duration=plan.duration,
             fmp=self.spec.fmp,
-            local_utility=float(np.clip(h, 0.0, 1.0)),
+            local_utility=_unit(h),
             declared_features=declared,
             payload={
                 "work": plan.work,
@@ -324,7 +330,7 @@ class JobAgent:
         """
         finish = t_start + duration
         wait = max(0.0, t_start - now)
-        phi_jct = float(np.clip(duration / max(duration + wait, 1e-9), 0.0, 1.0))
+        phi_jct = _unit(duration / max(duration + wait, 1e-9))
         if self.spec.qos_deadline is None:
             phi_qos = 1.0
         else:

@@ -387,16 +387,8 @@ def fixed_point_settle(
     if (prefetch is not None and first_pass is None
             and getattr(prefetch, "transformed", False)
             == (select_scores is not None)):
-        from ...kernels.common import KernelDispatchError
-
-        try:
-            first_pass, packed = prefetch.materialize(sel_scores)
-            members = packed.members
-        except KernelDispatchError:
-            # the fused first pass died in flight (device fault mid-round):
-            # the prefetch is pure speculation — clear from host state as
-            # if it had never been dispatched (selections are identical)
-            first_pass = None
+        first_pass, packed = prefetch.materialize(sel_scores)
+        members = packed.members
     rs = selector if isinstance(selector, RoundSelector) else None
     if rs is not None and packed is None:
         packed = rs.pack(members, view, sel_scores)

@@ -227,6 +227,11 @@ class IterationLog:
     # agents dropped from THIS round's bid collection (silent / erroring
     # past the retry budget) — the audit trail of graceful degradation
     n_dropped: int = 0
+    # where the round ran: the scoring backend ("numpy" | "ref" | "pallas")
+    # and the settle backend (those three, or "host-loop" for the
+    # per-window wis_select loop); "" for a round with no bids
+    score_backend: str = ""
+    settle_backend: str = ""
 
 
 @dataclass
@@ -369,8 +374,8 @@ class JasdaScheduler:
 
         self._grid_cache = FMPGridCache(maxsize=self.config.grid_cache_size)
         # sticky per-backend health shared by the scoring and settle
-        # dispatches: one device failure anywhere degrades BOTH down the
-        # pallas → ref → numpy ladder (kernels.common.BackendHealth)
+        # dispatches: one injected dispatch fault anywhere degrades BOTH
+        # down the pallas → ref → numpy ladder (kernels.common.BackendHealth)
         from ..kernels.common import BackendHealth
 
         self.backend_health = BackendHealth()
@@ -392,6 +397,16 @@ class JasdaScheduler:
         self._wis_selector = make_round_selector(self.config.wis_impl,
                                                  mesh=self.config.mesh,
                                                  health=self.backend_health)
+
+    @property
+    def settle_backend(self) -> str:
+        """Where the WIS settle runs now ("host-loop" for the per-window
+        loop); moves down the ladder only after an injected fault."""
+        from .wis import RoundSelector
+
+        sel = self._wis_selector
+        return sel._effective_impl() if isinstance(sel, RoundSelector) \
+            else "host-loop"
 
     # -- membership -----------------------------------------------------------
     def add_job(self, agent: JobAgent, now: float) -> None:
@@ -850,6 +865,9 @@ class JasdaScheduler:
                 now, prep.windows[0], prep.bidders, rr.n_bids, len(rr.selected),
                 rr.total_score, n_windows=len(prep.windows),
                 n_conflicts=rr.n_conflicts, n_dropped=prep.n_dropped,
+                score_backend=(prep.handle.backend
+                               if prep.handle is not None else ""),
+                settle_backend=self.settle_backend if prep.fit else "",
             )
         )
         return rr

@@ -130,7 +130,7 @@ class JobFeatures:
         """Fraction of the job's remaining work covered by this variant."""
         if work_remaining <= 0:
             return 1.0
-        return float(np.clip(work_in_variant / work_remaining, 0.0, 1.0))
+        return min(max(float(work_in_variant / work_remaining), 0.0), 1.0)
 
 
 # ---------------------------------------------------------------------------
@@ -260,27 +260,23 @@ class ScoreHandle:
     boundary.  The pipeline (core/pipeline.py) dispatches round k, overlaps
     host work for round k+1 while the scores are in flight, and settles k
     via ``result()``.  The numpy small-pool path is eager (already a host
-    array) so ``result()`` is free.
+    array) so ``result()`` is free.  An error raised while the scores
+    materialize propagates: there is no host recompute to hide it behind.
 
     Device handles keep the BUCKET-PADDED score array (``m`` marks the real
     pool size, sliced off at ``result()``): the padded shape is what lets
     the fused settle dispatch (``core.wis.RoundSelector.predispatch``)
     gather selection weights from :attr:`device_scores` without a per-pool-
     size retrace — pool indices are always < m ≤ m_pad, so padding never
-    leaks into a selection.
+    leaks into a selection.  ``backend`` names the backend that computed
+    the scores ("numpy", "ref" or "pallas").
     """
 
-    def __init__(self, scores, m: Optional[int] = None, fallback=None,
-                 health=None, backend: Optional[str] = None):
+    def __init__(self, scores, m: Optional[int] = None,
+                 backend: str = "numpy"):
         self._scores = scores
         self._m = m
-        # host recompute closure (the numpy reference scores) + the sticky
-        # health to notify: an ASYNC device failure only surfaces when the
-        # in-flight array materializes, so result() is the last line of the
-        # degradation ladder
-        self._fallback = fallback
-        self._health = health
-        self._backend = backend
+        self.backend = backend
 
     @property
     def in_flight(self) -> bool:
@@ -294,20 +290,9 @@ class ScoreHandle:
 
     def result(self) -> np.ndarray:
         if not isinstance(self._scores, np.ndarray):
-            try:
-                # np.asarray on a jax array blocks until the computation lands
-                arr = np.asarray(self._scores, dtype=np.float64)
-                self._scores = arr[: self._m] if self._m is not None else arr
-            except Exception as exc:
-                if self._fallback is None:
-                    raise
-                # device died after the async launch: degrade to the host
-                # recompute and make the failure sticky so the NEXT round
-                # never dispatches on this backend again
-                if self._health is not None and self._backend is not None:
-                    self._health.mark_failed(
-                        self._backend, f"in-flight materialize: {exc}")
-                self._scores = np.asarray(self._fallback(), np.float64)
+            # np.asarray on a jax array blocks until the computation lands
+            arr = np.asarray(self._scores, dtype=np.float64)
+            self._scores = arr[: self._m] if self._m is not None else arr
         return self._scores
 
 
@@ -371,8 +356,9 @@ def score_round_async(
         view=view,
     )
     def _numpy_scores() -> np.ndarray:
-        # host float64 reference: the ladder's last rung, also the small-
-        # pool fast path.  Ranks match the legacy per-window path.
+        # host float64 reference: the small-pool fast path and the last
+        # rung of the injected-fault ladder.  Ranks match the legacy
+        # per-window path.
         if recheck:
             from ..kernels.jasda_score.ops import score_variants_numpy
 
@@ -389,15 +375,12 @@ def score_round_async(
     if impl is None and m < SMALL_POOL_M:
         # device-dispatch overhead dominates tiny pools; same math on host
         impl = "numpy"
-    dev_impl = impl
-    if dev_impl is not None and dev_impl != "numpy" and health is not None:
-        dev_impl = health.resolve(dev_impl)
-    if dev_impl is None and health is not None:
-        # resolve the auto choice so sticky failures steer it too
+    if impl is None:
         import jax
 
-        dev_impl = health.resolve(
-            "pallas" if jax.default_backend() == "tpu" else "ref")
+        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
+    # sticky injected faults steer the choice down the ladder
+    dev_impl = health.resolve(impl) if health is not None else impl
     if dev_impl == "numpy":
         return ScoreHandle(_numpy_scores())
 
@@ -407,9 +390,9 @@ def score_round_async(
     # trim=False keeps the bucket-padded device array on the handle: the
     # fused settle dispatch gathers weights from it shape-stably (padded
     # rows are self-masking, and result() slices back to m on the host).
-    # With a BackendHealth attached the dispatch walks the degradation
-    # ladder: a failing backend is marked sick (sticky) and the round
-    # re-dispatches one rung down, bottoming out at the host numpy path.
+    # With a BackendHealth attached an injected fault walks the
+    # degradation ladder: the faulted backend is marked sick (sticky) and
+    # the round re-dispatches one rung down, bottoming out at host numpy.
     while True:
         try:
             scores, _, _ = score_variants(
@@ -422,8 +405,7 @@ def score_round_async(
                 trim=False,
                 mesh=mesh,
             )
-            return ScoreHandle(scores, m=m, fallback=_numpy_scores,
-                               health=health, backend=dev_impl)
+            return ScoreHandle(scores, m=m, backend=dev_impl)
         except KernelDispatchError as exc:
             if health is None:
                 raise

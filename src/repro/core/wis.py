@@ -299,33 +299,15 @@ class SettlePrefetch:
     first pass.
     """
 
-    def __init__(self, packed: PackedSettle, raw_sel, selector: "RoundSelector",
-                 transformed: bool = False):
+    def __init__(self, packed: PackedSettle, raw_sel, transformed: bool = False):
         self.packed = packed
         self._raw = raw_sel
-        self.selector = selector
         self.transformed = transformed
 
     def materialize(self, scores: np.ndarray):
         packed = self.packed
-        try:
-            sel = np.asarray(self._raw)[: packed.n_windows]
-        except Exception as exc:
-            # the fused dispatch died IN FLIGHT (device lost after the async
-            # launch): surface it as a typed dispatch error so the settle
-            # falls back to the unfused path, and mark the backend so the
-            # sticky ladder never re-trusts it
-            from ..kernels.common import KernelDispatchError
-
-            health = getattr(self.selector, "health", None)
-            backend = getattr(self.selector, "impl", "unknown")
-            if health is not None and not isinstance(exc, KernelDispatchError):
-                health.mark_failed(backend, f"prefetch materialize: {exc}")
-            if isinstance(exc, KernelDispatchError):
-                raise
-            raise KernelDispatchError(
-                backend, "settle_prefetch",
-                tuple(packed.idx_sorted.shape), cause=exc) from exc
+        # blocks on the device; an error raised in flight propagates
+        sel = np.asarray(self._raw)[: packed.n_windows]
         first_pass = [
             [int(i) for i in packed.idx_sorted[k][np.flatnonzero(sel[k])]]
             for k in range(packed.n_windows)
@@ -404,8 +386,8 @@ class RoundSelector:
         # rows of every batched dispatch; host backend has nothing to shard
         self.mesh = mesh if impl in ("ref", "pallas") else None
         # sticky per-backend health (kernels.common.BackendHealth), shared
-        # with the scheduler's scoring dispatches: a failed device backend
-        # degrades every future settle down the pallas → ref → numpy ladder
+        # with the scheduler's scoring dispatches: an injected fault on a
+        # device backend degrades every future settle down the ladder
         self.health = health
 
     def _effective_impl(self) -> str:
@@ -570,9 +552,9 @@ class RoundSelector:
             wp = np.concatenate([w, np.zeros((rb - r, w.shape[1]), w.dtype)])
             pp = np.concatenate(
                 [pred, np.zeros((rb - r, pred.shape[1]), pred.dtype)])
-        # degradation ladder: a failing device backend is marked sick
-        # (sticky) and the dispatch retries one rung down, ending at the
-        # host float64 DP, which cannot fail
+        # degradation ladder (injected faults only): the faulted backend is
+        # marked sick (sticky) and the dispatch retries one rung down,
+        # ending at the host float64 DP, which cannot fail
         while impl != "numpy":
             try:
                 sel, _ = wis_ops.wis_settle_batch(
@@ -631,14 +613,14 @@ class RoundSelector:
                 handle.device_scores, idx.astype(np.int32), idx >= 0, pred,
                 impl=self._effective_impl(), mesh=self.mesh, transform=tr)
         except KernelDispatchError as exc:
-            # speculation is optional: mark the backend sick and settle
-            # without fusion (the settle half re-clears from host scores)
+            # an injected fault: speculation is optional, so mark the
+            # backend sick and settle without fusion (the settle half
+            # re-clears from host scores)
             if self.health is None:
                 raise
             self.health.mark_failed(exc.backend, str(exc))
             return None
-        return SettlePrefetch(packed, sel, self,
-                              transformed=transform is not None)
+        return SettlePrefetch(packed, sel, transformed=transform is not None)
 
 
 def predispatch_settle(selector, backend, n_windows: int, win_idx, view,

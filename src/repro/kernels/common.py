@@ -1,19 +1,19 @@
-"""Shared kernel utilities: dispatch policy, fault surface + numerics.
+"""Shared kernel utilities: dispatch policy, fault injection + numerics.
 
 Besides the numerics helpers this module owns the kernel packages' fault
-surface: every device dispatch in ``jasda_score`` and ``wis_dp`` funnels
-raw XLA/pallas errors into a typed :class:`KernelDispatchError` (backend +
-bucketed operand shape attached), and :class:`BackendHealth` is the sticky
-per-backend ladder state the scheduler uses to degrade pallas → ref →
-host numpy without ever re-trying a backend that failed once (so the
-zero-retrace contract holds per HEALTHY backend: a jit cache is only ever
-consulted while its backend is trusted, and abandoning a backend abandons
-its cache wholesale instead of thrashing it).
+surface.  :func:`inject_dispatch_fault` is the deterministic fault-
+injection hook for tests and the simulator's ``device_dispatch_fail``
+event: it arms ONE failure for a named backend, and the next dispatch on
+that backend raises :class:`KernelDispatchError` (backend + bucketed
+operand shape attached) before touching the device.  :class:`BackendHealth`
+is the sticky per-backend ladder state the scheduler uses to walk pallas →
+ref → host numpy on such an injected fault, never re-trying a backend that
+failed once (so the zero-retrace contract holds per HEALTHY backend).
 
-``inject_dispatch_fault`` is the deterministic fault-injection hook for
-tests and the simulator's ``device_dispatch_fail`` event: it arms ONE
-failure for a named backend; the next dispatch on that backend raises
-``KernelDispatchError`` before touching the device.
+Only injected faults walk the ladder.  A real XLA / Mosaic error from a
+dispatch, a compile or an in-flight result propagates as raised and ends
+the run: a silent step down the ladder would hide that the device path
+never ran.
 """
 from __future__ import annotations
 
@@ -39,19 +39,16 @@ __all__ = [
 
 NEG_INF = -1e30  # large-negative for masking (avoids inf-inf NaNs in bf16)
 
-#: backend order the scheduler walks when a dispatch fails; "numpy" is the
-#: host float64 reference and never raises KernelDispatchError.
+#: backend order the scheduler walks on an injected dispatch fault; "numpy"
+#: is the host float64 reference and never raises KernelDispatchError.
 DEGRADATION_LADDER = ("pallas", "ref", "numpy")
 
 
 class KernelDispatchError(RuntimeError):
-    """A device dispatch failed; carries backend + bucketed operand shape.
+    """An injected dispatch fault; carries backend + bucketed operand shape.
 
-    Raised instead of whatever XLA/pallas error surfaced so callers can
-    (a) tell WHICH backend of a fused round failed and at what bucket
-    shape (real-TPU debugging: bucket shape identifies the retraced
-    executable), and (b) drive the degradation ladder on a stable type
-    rather than string-matching runtime errors.
+    Raised only by :func:`check_dispatch_fault`, so catching this type
+    (the degradation ladder does) can never swallow a real device error.
     """
 
     def __init__(self, backend: str, op: str,
@@ -70,9 +67,11 @@ class KernelDispatchError(RuntimeError):
 class BackendHealth:
     """Sticky per-backend health: once a backend fails it stays failed.
 
-    One instance is shared by a scheduler's scoring AND settle dispatches
-    so a pallas failure observed while scoring also steers the round's
-    WIS settle away from pallas.  ``resolve(preferred)`` walks the
+    Filled only by injected faults (:class:`KernelDispatchError`), so an
+    empty :meth:`failed_backends` means every dispatch ran where it was
+    asked to.  One instance is shared by a scheduler's scoring AND settle
+    dispatches so a pallas fault observed while scoring also steers the
+    round's WIS settle away from pallas.  ``resolve(preferred)`` walks the
     degradation ladder from the preferred backend to the first healthy
     one ("numpy" is always healthy — the host reference path has no
     device to lose).  Stickiness is what makes fault landing
@@ -141,7 +140,10 @@ def restore_dispatch_faults(snap: Dict[str, int]) -> None:
 
 def check_dispatch_fault(backend: str, op: str,
                          shape: Tuple[int, ...] = ()) -> None:
-    """Raise KernelDispatchError if a fault is armed for ``backend``."""
+    """Raise KernelDispatchError if a fault is armed for ``backend``.
+
+    The only place a KernelDispatchError is raised.
+    """
     n = _ARMED_FAULTS.get(backend, 0)
     if n > 0:
         if n == 1:
@@ -163,22 +165,45 @@ def use_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def log_ndtr(z: jnp.ndarray) -> jnp.ndarray:
-    """Numerically stable log Φ(z) built from lax primitives only.
+# Numerical Recipes' Chebyshev fit of erfc: for y >= 0,
+#   erfc(y) = t * exp(-y^2 + P(t)),  t = 1 / (1 + y/2),
+# with fractional error below 1.2e-7 everywhere (Press et al., "Numerical
+# Recipes", 2nd ed., §6.2).  t * exp(P(t)) is erfcx(y), so log erfc(y) is
+# formed in log space and never underflows.  Coefficients low order first.
+_ERFC_P = (-1.26551223, 1.00002368, 0.37409196, 0.09678418, -0.18628806,
+           0.27886807, -1.13520398, 1.48851587, -0.82215223, 0.17087277)
 
-    Safe inside Pallas kernel bodies (no scipy).  For z ≥ −1 uses
-    log1p(−Φ̄(z)) via erfc; for z < −1 uses the erfc-scaled form
-    log(erfcx(−z/√2)/2) − z²/2, stable far into the left tail.
+
+def log_ndtr(z: jnp.ndarray) -> jnp.ndarray:
+    """log Φ(z) from exp / log / log1p and a polynomial only.
+
+    Mosaic lowers none of erf, erfc or expm1, so the kernel body uses the
+    rational erfc above.  Left tail (z < 0): log Φ(z) = log erfc(|z|/√2) −
+    log 2, exact in log space down to any z.  Right side: log1p(−erfc/2).
+    Agrees with ``scipy.special.log_ndtr`` to a relative 2e-5 over
+    z ∈ [−40, 10] in float32.
     """
     z = jnp.asarray(z)
-    sqrt_half = 0.7071067811865476
-    x = z * sqrt_half
-    # right/central region
-    right = jnp.log1p(-0.5 * jax.lax.erfc(x))
-    # left tail: Φ(z) = erfc(-x)/2 = erfcx(-x)·exp(-x²)/2
-    left = jnp.log(0.5 * jax.lax.erfc(-x).clip(min=1e-300))
-    # erfc underflows around z < -37 in f64 / z < -13 in f32; asymptotic form:
-    #   logΦ(z) ≈ -z²/2 - log(-z√(2π))  for z → -∞
-    asym = -0.5 * z * z - jnp.log(-z * 2.5066282746310002 + 1e-30)
-    out = jnp.where(z >= -1.0, right, jnp.where(z >= -10.0, left, asym))
-    return out
+    y = jnp.abs(z) * 0.7071067811865476
+    t = 1.0 / (1.0 + 0.5 * y)
+    poly = _ERFC_P[-1]
+    for c in _ERFC_P[-2::-1]:
+        poly = poly * t + c
+    log_erfc = jnp.log(t) + poly - y * y
+    left = log_erfc - 0.6931471805599453
+    right = jnp.log1p(-0.5 * jnp.exp(log_erfc))
+    return jnp.where(z < 0, left, right)
+
+
+def expm1(x: jnp.ndarray) -> jnp.ndarray:
+    """exp(x) − 1 accurate near 0, from exp and log only (Kahan's form).
+
+    ``jnp.expm1`` has no Mosaic lowering.  With u = exp(x):
+    (u − 1) · x / log(u) cancels the rounding of u; u == 1 returns x, and
+    u == 0 (x = −inf) returns −1.
+    """
+    u = jnp.exp(x)
+    um1 = u - 1.0
+    safe = jnp.logical_and(u != 1.0, um1 != -1.0)
+    scaled = um1 * x / jnp.log(jnp.where(safe, u, 2.0))
+    return jnp.where(u == 1.0, x, jnp.where(um1 == -1.0, -1.0, scaled))

@@ -20,9 +20,11 @@ buckets so drifting pool sizes reuse one executable per bucket.
 
 Tiling: grid over M blocks; each program holds (BM, Fj)+(BM, Fs) feature
 tiles, the (BM, T) FMP grid tiles, the (BM, 1) λ/capacity/θ columns, and
-produces (BM,) scores + eligibility.  T and F are padded to lane multiples
-by ops.py.  A GPU port would reduce across a warp per variant; on TPU the
-whole (BM, T) tile reduces in one vectorized `sum` on the VPU.
+produces (BM,) scores + eligibility.  Feature and grid blocks span their
+whole (narrow) last dim, which Mosaic accepts as-is.  The body uses only
+primitives Mosaic lowers: ``common.log_ndtr`` and ``common.expm1`` stand in
+for erfc and expm1.  A GPU port would reduce across a warp per variant; on
+TPU the whole (BM, T) tile reduces in one vectorized `sum` on the VPU.
 """
 from __future__ import annotations
 
@@ -33,7 +35,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from ..common import log_ndtr
+from ..common import expm1, log_ndtr
 
 __all__ = ["score_variants_pallas", "TRACE_COUNT"]
 
@@ -76,7 +78,7 @@ def _score_kernel(
     logphi = jnp.where(safe_det, 0.0, log_ndtr(jnp.where(sg > 0, z, 0.0)))
     logphi = jnp.where(viol_det, -jnp.inf, logphi)
     log_surv = jnp.sum(logphi, axis=-1)  # (BM,)
-    p_exceed = -jnp.expm1(log_surv)
+    p_exceed = -expm1(log_surv)
     eligible = p_exceed <= theta
 
     score_ref[...] = jnp.where(eligible, score, 0.0)[None, :]

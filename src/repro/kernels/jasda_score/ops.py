@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..common import KernelDispatchError, check_dispatch_fault, use_interpret
+from ..common import check_dispatch_fault, use_interpret
 from .kernel import TRACE_COUNT as _PALLAS_TRACE_COUNT
 from .kernel import score_variants_pallas
 from .ref import score_variants_reference
@@ -112,7 +112,6 @@ def _sharded_score_fn(mesh, impl: str, block_m: int, interpret: bool):
     fn = _SHARDED_SCORE_CACHE.get(key)
     if fn is not None:
         return fn
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as PS
 
     row = PS(tuple(mesh.axis_names))
@@ -129,12 +128,12 @@ def _sharded_score_fn(mesh, impl: str, block_m: int, interpret: bool):
                 theta=th, block_m=block_m, interpret=interpret)
             return score, elig
         out_specs = (row, row)
-    # check_rep=False: pallas_call has no replication rule, and scoring is
+    # check_vma=False: pallas_call has no varying-axes rule, and scoring is
     # row-independent anyway (no cross-shard collectives in the body)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         body, mesh=mesh,
         in_specs=(row, row, rep, rep, row, row, row, row, row),
-        out_specs=out_specs, check_rep=False)
+        out_specs=out_specs, check_vma=False)
 
     @jax.jit
     def call(fj, fs, alphas, betas, mu, sg, lam, cap, th):
@@ -222,45 +221,30 @@ def score_variants(
         if n_shards <= 1 or not spec_sharded(auction_row_spec(mesh, m_pad)):
             n_shards = 1  # degenerate / non-dividing mesh: unsharded path
 
-    # typed fault surface: injected faults fire before the device is
-    # touched; raw XLA/pallas errors are re-raised as KernelDispatchError
-    # carrying backend + bucketed shape (the degradation ladder keys on it)
+    # injected faults fire before the device is touched; a real XLA/pallas
+    # error propagates as raised
     check_dispatch_fault(impl, "score_variants", (m_pad, fj.shape[1]))
     if impl == "ref":
-        try:
-            if n_shards > 1:
-                score, elig, p_exceed = _sharded_score_fn(mesh, "ref", 0, False)(
-                    fj, fs, alphas, betas, mu_p, sg_p, lam_v, cap_v, th_v)
-            else:
-                score, elig, p_exceed = _score_ref_jit(
-                    fj, fs, alphas, betas, mu_p, sg_p, lam_v, cap_v, th_v
-                )
-        except KernelDispatchError:
-            raise
-        except Exception as exc:
-            raise KernelDispatchError(
-                "ref", "score_variants", (m_pad, fj.shape[1]), cause=exc
-            ) from exc
+        if n_shards > 1:
+            score, elig, p_exceed = _sharded_score_fn(mesh, "ref", 0, False)(
+                fj, fs, alphas, betas, mu_p, sg_p, lam_v, cap_v, th_v)
+        else:
+            score, elig, p_exceed = _score_ref_jit(
+                fj, fs, alphas, betas, mu_p, sg_p, lam_v, cap_v, th_v
+            )
         return score[:end], elig[:end], p_exceed[:end]
 
     # per-SHARD row extent bounds the pallas block size under sharding
     bm = min(block_m, max(8, m_pad // n_shards))
-    try:
-        if n_shards > 1:
-            score, elig = _sharded_score_fn(mesh, "pallas", bm, use_interpret())(
-                fj, fs, alphas, betas, mu_p, sg_p, lam_v, cap_v, th_v)
-        else:
-            score, elig = score_variants_pallas(
-                fj, fs, alphas, betas, mu_p, sg_p,
-                lam=lam_v, capacity=cap_v, theta=th_v,
-                block_m=bm, interpret=use_interpret(),
-            )
-    except KernelDispatchError:
-        raise
-    except Exception as exc:
-        raise KernelDispatchError(
-            impl, "score_variants", (m_pad, fj.shape[1]), cause=exc
-        ) from exc
+    if n_shards > 1:
+        score, elig = _sharded_score_fn(mesh, "pallas", bm, use_interpret())(
+            fj, fs, alphas, betas, mu_p, sg_p, lam_v, cap_v, th_v)
+    else:
+        score, elig = score_variants_pallas(
+            fj, fs, alphas, betas, mu_p, sg_p,
+            lam=lam_v, capacity=cap_v, theta=th_v,
+            block_m=bm, interpret=use_interpret(),
+        )
     # kernel does not return p_exceed; recompute lazily only if needed
     return score[:end], elig[:end], None
 

@@ -24,7 +24,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..common import log_ndtr
+from ..common import expm1, log_ndtr
 
 __all__ = ["score_variants_reference"]
 
@@ -62,6 +62,6 @@ def score_variants_reference(
     z = jnp.where(sigma > 0, z, jnp.where(mu <= cap_v, jnp.inf, -jnp.inf))
     logphi = jnp.where(jnp.isposinf(z), 0.0, log_ndtr(z))
     log_surv = jnp.sum(logphi, axis=-1)
-    p_exceed = -jnp.expm1(log_surv)
+    p_exceed = -expm1(log_surv)
     eligible = p_exceed <= th_v
     return jnp.where(eligible, score, 0.0), eligible, p_exceed

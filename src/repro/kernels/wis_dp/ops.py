@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..common import KernelDispatchError, check_dispatch_fault, use_interpret
+from ..common import check_dispatch_fault, use_interpret
 from .kernel import wis_batch_pallas, wis_dp_pallas
 from .ref import wis_batch_reference, wis_dp_reference
 
@@ -120,7 +120,6 @@ def _sharded_settle_fn(mesh, impl: str, interpret: bool, fused: bool,
     fn = _SHARDED_SETTLE_CACHE.get(key)
     if fn is not None:
         return fn
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as PS
 
     row = PS(tuple(mesh.axis_names))
@@ -137,29 +136,29 @@ def _sharded_settle_fn(mesh, impl: str, interpret: bool, fused: bool,
             return kernel(_fused_weights(scores, idx, mask, transform), pred)
 
         if transformed:
-            sharded = shard_map(
+            sharded = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(rep, rep, row, row, row),
-                out_specs=(row, row), check_rep=False)
+                out_specs=(row, row), check_vma=False)
 
             @jax.jit
             def call(scores, transform, idx, mask, pred):
                 TRACE_COUNT["settle_ref" if impl == "ref" else "settle_pallas"] += 1
                 return sharded(scores, transform, idx, mask, pred)
         else:
-            sharded = shard_map(
+            sharded = jax.shard_map(
                 lambda scores, idx, mask, pred: body(scores, None, idx, mask, pred),
                 mesh=mesh, in_specs=(rep, row, row, row),
-                out_specs=(row, row), check_rep=False)
+                out_specs=(row, row), check_vma=False)
 
             @jax.jit
             def call(scores, idx, mask, pred):
                 TRACE_COUNT["settle_ref" if impl == "ref" else "settle_pallas"] += 1
                 return sharded(scores, idx, mask, pred)
     else:
-        sharded = shard_map(
+        sharded = jax.shard_map(
             kernel, mesh=mesh, in_specs=(row, row),
-            out_specs=(row, row), check_rep=False)
+            out_specs=(row, row), check_vma=False)
 
         @jax.jit
         def call(weights, pred):
@@ -199,20 +198,13 @@ def wis_settle_batch(weights, pred, *, impl: Optional[str] = None, mesh=None):
     pred = jnp.asarray(pred, jnp.int32)
     if impl is None:
         impl = "pallas" if jax.default_backend() == "tpu" else "ref"
-    shape = tuple(int(s) for s in weights.shape)
-    check_dispatch_fault(impl, "wis_settle_batch", shape)
-    try:
-        if _settle_shards(mesh, weights.shape[0]) > 1:
-            return _sharded_settle_fn(mesh, impl, use_interpret(), False, False)(
-                weights, pred)
-        if impl == "ref":
-            return _settle_ref_jit(weights, pred)
-        return _settle_pallas_jit(weights, pred, use_interpret())
-    except KernelDispatchError:
-        raise
-    except Exception as exc:
-        raise KernelDispatchError(
-            impl, "wis_settle_batch", shape, cause=exc) from exc
+    check_dispatch_fault(impl, "wis_settle_batch", weights.shape)
+    if _settle_shards(mesh, weights.shape[0]) > 1:
+        return _sharded_settle_fn(mesh, impl, use_interpret(), False, False)(
+            weights, pred)
+    if impl == "ref":
+        return _settle_ref_jit(weights, pred)
+    return _settle_pallas_jit(weights, pred, use_interpret())
 
 
 def wis_settle_fused(scores, idx, mask, pred, *, impl: Optional[str] = None,
@@ -241,28 +233,21 @@ def wis_settle_fused(scores, idx, mask, pred, *, impl: Optional[str] = None,
         impl = "pallas" if jax.default_backend() == "tpu" else "ref"
     if transform is not None:
         transform = jnp.asarray(transform, jnp.float32)
-    shape = tuple(int(s) for s in idx.shape)
-    check_dispatch_fault(impl, "wis_settle_fused", shape)
-    try:
-        if _settle_shards(mesh, idx.shape[0]) > 1:
-            fn = _sharded_settle_fn(mesh, impl, use_interpret(), True,
-                                    transform is not None)
-            if transform is not None:
-                return fn(scores, transform, idx, mask, pred)
-            return fn(scores, idx, mask, pred)
+    check_dispatch_fault(impl, "wis_settle_fused", idx.shape)
+    if _settle_shards(mesh, idx.shape[0]) > 1:
+        fn = _sharded_settle_fn(mesh, impl, use_interpret(), True,
+                                transform is not None)
         if transform is not None:
-            if impl == "ref":
-                return _settle_ref_fused_tr_jit(scores, transform, idx, mask, pred)
-            return _settle_pallas_fused_tr_jit(scores, transform, idx, mask, pred,
-                                               use_interpret())
+            return fn(scores, transform, idx, mask, pred)
+        return fn(scores, idx, mask, pred)
+    if transform is not None:
         if impl == "ref":
-            return _settle_ref_fused_jit(scores, idx, mask, pred)
-        return _settle_pallas_fused_jit(scores, idx, mask, pred, use_interpret())
-    except KernelDispatchError:
-        raise
-    except Exception as exc:
-        raise KernelDispatchError(
-            impl, "wis_settle_fused", shape, cause=exc) from exc
+            return _settle_ref_fused_tr_jit(scores, transform, idx, mask, pred)
+        return _settle_pallas_fused_tr_jit(scores, transform, idx, mask, pred,
+                                           use_interpret())
+    if impl == "ref":
+        return _settle_ref_fused_jit(scores, idx, mask, pred)
+    return _settle_pallas_fused_jit(scores, idx, mask, pred, use_interpret())
 
 
 def wis_dp(weights, pred, *, impl: Optional[str] = None):

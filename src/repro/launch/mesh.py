@@ -45,13 +45,8 @@ def make_production_mesh(*, multi_pod: bool = False):
         n_needed *= s
     if jax.device_count() < n_needed:
         return jax.make_mesh((jax.local_device_count(),), ("data",))
-    # axis_types only exists on newer jax; omit it where unavailable (the
-    # default — auto sharding propagation — is what we want anyway)
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_auction_mesh(n_shards: Optional[int] = None):
@@ -68,7 +63,10 @@ def make_auction_mesh(n_shards: Optional[int] = None):
     avail = jax.local_device_count()
     n = avail if n_shards is None else min(int(n_shards), avail)
     n = _pow2_floor(max(n, 1))
-    return jax.make_mesh((n,), (AUCTION_AXIS,))
+    # Auto axes: shard_map places the round, and host-side slicing of its
+    # (possibly unevenly trimmed) outputs needs no explicit out_sharding
+    return jax.make_mesh((n,), (AUCTION_AXIS,),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def mesh_chips(mesh) -> int:

@@ -27,6 +27,7 @@ from ..core import JasdaScheduler, SliceSpec
 from ..service import (AcceptAll, BoundedQueue, BurstArrivals,
                        DiurnalArrivals, JasdaService, PoissonArrivals,
                        ServiceConfig, TokenBucket)
+from .compile_cache import enable_compile_cache
 
 _GB = 1 << 30
 
@@ -99,6 +100,7 @@ def main(argv=None):
     ap.add_argument("--json", action="store_true",
                     help="emit the final ServiceStats as one JSON line")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     store = None
     if args.checkpoint_dir is not None:

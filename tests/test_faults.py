@@ -242,6 +242,55 @@ def test_ladder_degradation_preserves_results_and_traces():
 # fault plans + the agent-fault gate
 # ---------------------------------------------------------------------------
 
+class _InFlightFailure:
+    """A device array whose materialization fails (a device lost in flight)."""
+
+    shape = (256,)
+
+    def __getitem__(self, _):
+        return self
+
+    def __array__(self, dtype=None, copy=None):
+        raise RuntimeError("device lost in flight")
+
+
+def _raise_dispatch(*_a, **_k):
+    raise RuntimeError("XLA compile error")
+
+
+# a real (non-injected) device error at each place the ladder used to hide
+# one: the scoring dispatch, the settle dispatch (fused first pass and
+# re-clear), and the in-flight scores at ScoreHandle.result()
+@pytest.mark.parametrize("site,score_impl,wis_impl,match", [
+    ("score_dispatch", "ref", None, "XLA compile error"),
+    ("settle_dispatch", "ref", "ref", "XLA compile error"),
+    ("score_result", "ref", None, "device lost in flight"),
+])
+def test_real_device_error_ends_the_service_run(monkeypatch, site,
+                                                score_impl, wis_impl, match):
+    from repro.kernels.jasda_score import ops as score_ops
+    from repro.kernels.wis_dp import ops as wis_ops
+    from repro.service import JasdaService, PoissonArrivals, ServiceConfig
+
+    if site == "score_dispatch":
+        monkeypatch.setattr(score_ops, "_score_ref_jit", _raise_dispatch)
+    elif site == "settle_dispatch":
+        monkeypatch.setattr(wis_ops, "_settle_ref_fused_jit", _raise_dispatch)
+        monkeypatch.setattr(wis_ops, "_settle_ref_jit", _raise_dispatch)
+    else:
+        monkeypatch.setattr(
+            score_ops, "_score_ref_jit",
+            lambda *a: (_InFlightFailure(),) * 3)
+    sched = JasdaScheduler(_slices(), SchedulerConfig(
+        score_impl=score_impl, wis_impl=wis_impl))
+    svc = JasdaService(sched, PoissonArrivals(2.0, seed=0),
+                       config=ServiceConfig(t_end=20.0))
+    with pytest.raises(RuntimeError, match=match):
+        svc.run()
+    # nothing degraded: the ladder is for injected faults only
+    assert sched.backend_health.failed_backends() == {}
+
+
 def test_fault_event_validates_kind():
     with pytest.raises(ValueError):
         FaultEvent(t=1.0, kind="meteor_strike")

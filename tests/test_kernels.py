@@ -144,3 +144,36 @@ def test_wis_dp_kernel_matches_ref(m):
     dp_r, take_r = wis_dp_reference(jnp.array(w), jnp.array(pred))
     np.testing.assert_allclose(np.asarray(dp_k), np.asarray(dp_r), atol=1e-5)
     np.testing.assert_array_equal(np.asarray(take_k), np.asarray(take_r))
+
+
+# rows as the host settle pack lays them out: sorted by end, zero-weight
+# padded lanes at the tail with predecessor count L.  "vmem" lowers the
+# SMEM row bound so the lanes take the VMEM table layout; each layout gets
+# its own lane count so neither reuses the other's jit trace.
+@pytest.mark.parametrize("layout,lanes", [("smem", 2048), ("vmem", 3072)])
+def test_wis_batch_kernel_layouts_match_ref(monkeypatch, layout, lanes):
+    from repro.kernels.wis_dp import kernel as wis_kernel
+    from repro.kernels.wis_dp.ref import wis_batch_reference
+
+    rows, real = 3, lanes - 500
+    if layout == "vmem":
+        monkeypatch.setattr(wis_kernel, "SMEM_MAX_LANES", 1024)
+    rng = np.random.default_rng(lanes)
+    ends = np.sort(rng.uniform(0, 500, (rows, real)), axis=1)
+    starts = ends - rng.uniform(0.5, 20, (rows, real))
+    w = np.zeros((rows, lanes), np.float32)
+    w[:, :real] = rng.uniform(0, 1, (rows, real))
+    w[:, :real][rng.uniform(size=(rows, real)) < 0.2] = 0.0  # banned lanes
+    pred = np.full((rows, lanes), lanes, np.int32)
+    for k in range(rows):
+        pred[k, :real] = np.searchsorted(ends[k], starts[k], side="right")
+    sel, tot = wis_kernel.wis_batch_pallas(jnp.array(w), jnp.array(pred),
+                                           interpret=True)
+    sel_r, tot_r = wis_batch_reference(jnp.array(w), jnp.array(pred))
+    np.testing.assert_array_equal(np.asarray(sel), np.asarray(sel_r))
+    np.testing.assert_allclose(np.asarray(tot), np.asarray(tot_r), atol=1e-5)
+    dp, take = wis_kernel.wis_dp_pallas(jnp.array(w[0]), jnp.array(pred[0]),
+                                        interpret=True)
+    dp_r, take_r = wis_dp_reference(jnp.array(w[0]), jnp.array(pred[0]))
+    np.testing.assert_allclose(np.asarray(dp), np.asarray(dp_r), atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(take), np.asarray(take_r))

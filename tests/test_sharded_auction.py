@@ -264,3 +264,24 @@ def test_large_round_sharded_equivalence_and_zero_retrace():
     assert _sig(base2) == _sig(shard2)
     after = (score_ops.trace_counts(), wis_ops.trace_counts())
     assert after == before, f"retraced: {before} -> {after}"
+
+
+@multi_device
+@pytest.mark.parametrize("impl", ["ref", "pallas"])
+def test_sharded_scores_trim_to_an_unaligned_pool(impl):
+    """trim=True slices a sharded score array back to a pool size no shard
+    boundary matches; the auction mesh's Auto axes keep that a plain slice."""
+    from repro.kernels.jasda_score.ops import score_variants
+
+    rng = np.random.default_rng(5)
+    m, t = 1000, 8
+    args = (rng.uniform(0, 1, (m, 1)), rng.uniform(0, 1, (m, 4)),
+            np.ones(1), np.full(4, 0.2), rng.uniform(1, 3, (m, t)),
+            rng.uniform(0.1, 0.5, (m, t)))
+    kw = dict(lam=0.5, capacity=2.5, theta=0.05, impl=impl)
+    one = score_variants(*args, **kw)
+    shard = score_variants(*args, mesh=make_auction_mesh(8), **kw)
+    assert np.asarray(shard[0]).shape == (m,)
+    for a, b in zip(one, shard):
+        if a is not None:
+            assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
