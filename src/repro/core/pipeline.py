@@ -80,17 +80,15 @@ class RoundPipeline:
         self.sched = scheduler
         self._spec = None  # speculative RoundPrep for the next tick
         self._consec_discards = 0
-        # observability: how often speculation paid off / was filtered / lost
-        self.stats = {"spec_hit": 0, "spec_filtered": 0, "spec_discarded": 0,
-                      "serial_prep": 0}
 
     # -- public ----------------------------------------------------------------
     def tick(self, now: float, next_time: Optional[float] = None) -> Optional[RoundResult]:
         """Run the round at ``now``; speculatively prepare ``next_time``."""
+        discarded = self._spec is not None
         prep = self._take_validated(now)
         if prep is None:
-            self.stats["serial_prep"] += 1
             prep = self.sched._prepare_round(now)
+            prep.n_spec_discarded = int(discarded)
         # Overlap window: the current round's scores are (possibly) in
         # flight; prepare the next round's host half now.  Only worthwhile
         # when something is actually in flight — eager paths (empty round,
@@ -135,7 +133,6 @@ class RoundPipeline:
         if spec is None:
             return None
         if spec.now != now or spec.epoch != self.sched._epoch:
-            self.stats["spec_discarded"] += 1
             self._consec_discards += 1
             self._discard(spec)
             return None
@@ -144,11 +141,11 @@ class RoundPipeline:
         kept = [k for k, w in enumerate(spec.windows)
                 if not reg.suppressed(w.slice_id, w.t_min)]
         if len(kept) == len(spec.windows):
-            self.stats["spec_hit"] += 1
             self._consec_discards = 0
+            spec.source = "spec_hit"
             return spec  # bit-identical to a serial preparation
-        self.stats["spec_filtered"] += 1
         self._consec_discards = 0
+        spec.source = "spec_filtered"
         # Some speculated windows were killed by the round that settled in
         # between.  Timeline/agents/ages are untouched (epoch matched), so
         # the surviving windows' bids are exactly what a fresh announcement

@@ -112,6 +112,8 @@ class GlobalAssignment(ClearingPolicy):
             return best  # conflicts were budget-only: greedy order stands
 
         evals = 0
+        # fixed-point work of every replay, added to the greedy baseline's
+        passes, reclears = best.n_passes, best.n_reclears
         members = packed.members if packed is not None else _pool_members(
             len(windows), win_idx)
         # replays compare on cheap state TOTALS (identical float-sum order
@@ -128,11 +130,15 @@ class GlobalAssignment(ClearingPolicy):
             return prefer
 
         def run_state(choice) -> _FixedPointState:
+            nonlocal passes, reclears
             st = _FixedPointState(windows, fit, win_idx, scores, view,
                                   members, selector, packed, work_budget,
                                   to_prefer(choice))
             st.seed(first_pass)
-            return st.run_to_fixed_point()
+            st.run_to_fixed_point()
+            passes += st.n_passes
+            reclears += st.n_reclears
+            return st
 
         def evaluate(choice: Sequence[Optional[int]]) -> bool:
             """Replay the fixed point under this assignment; keep if better.
@@ -152,7 +158,9 @@ class GlobalAssignment(ClearingPolicy):
             return True
 
         def finish() -> RoundResult:
-            return best_state.package(scores) if best_state is not None else best
+            rr = best_state.package(scores) if best_state is not None else best
+            rr.n_passes, rr.n_reclears = passes, reclears
+            return rr
 
         n_joint = 1
         for _, wins in clusters:
@@ -221,7 +229,9 @@ class GlobalAssignment(ClearingPolicy):
             for st in states:
                 if not st.active:
                     continue
-                for k in st.take_dirty():
+                ks = st.take_dirty()
+                st.n_reclears += len(ks)
+                for k in ks:
                     requests.append((k, st.banned))
                     owners.append((st, k))
             if not requests:
@@ -238,7 +248,10 @@ class GlobalAssignment(ClearingPolicy):
             if total > best_total + 1e-12:
                 best_total = total
                 best_state = st
-        return best_state.package(scores) if best_state is not None else best
+        rr = best_state.package(scores) if best_state is not None else best
+        rr.n_passes = best.n_passes + sum(st.n_passes for st in states)
+        rr.n_reclears = best.n_reclears + sum(st.n_reclears for st in states)
+        return rr
 
     # -- conflict structure ---------------------------------------------------
     @staticmethod

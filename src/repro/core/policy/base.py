@@ -169,6 +169,9 @@ class _FixedPointState:
         self.selected: List[List[int]] = [[] for _ in windows]
         self.dirty: List[int] = list(range(len(windows)))
         self.n_conflicts = 0
+        # work counters: resolve passes, and windows cleared by WIS
+        self.n_passes = 0
+        self.n_reclears = 0
         self.active = True  # False once the fixed point is reached
 
     def seed(self, first_pass: Sequence[Sequence[int]]) -> None:
@@ -184,6 +187,7 @@ class _FixedPointState:
         """Re-run WIS on the given windows over their unbanned candidates."""
         if not ks:
             return
+        self.n_reclears += len(ks)
         if self.rs is not None:
             for k, sel in zip(ks, self.rs.select(self.packed, ks, self.banned)):
                 self.selected[k] = sel
@@ -210,6 +214,7 @@ class _FixedPointState:
         """
         from ..types import OVERLAP_EPS
 
+        self.n_passes += 1
         fit, win_idx, sel_scores = self.fit, self.win_idx, self.sel_scores
         ts, te = self.view.t_start, self.view.t_end
         job_ids = self.view.job_ids
@@ -310,6 +315,8 @@ class _FixedPointState:
             n_bids=len(fit),
             n_conflicts=self.n_conflicts,
             selected_idx=tuple(selected_idx),
+            n_passes=self.n_passes,
+            n_reclears=self.n_reclears,
         )
 
 
@@ -397,6 +404,8 @@ def fixed_point_settle(
                           selector, packed, work_budget, prefer)
     if first_pass is not None:
         st.seed(first_pass)
+        # the adopted first pass cleared every window once
+        st.n_reclears = len(windows)
 
     # fixed point: each pass bans ≥ 1 variant or terminates, so the loop is
     # bounded by the pool size

@@ -72,6 +72,7 @@ from .negotiation.messages import (LOSS_SLICE_FAILED, LossReport,
                                    build_shed_feedback)
 from .policy import ClearingPolicy, GreedyWIS, Policy
 from .scoring import ScoringPolicy, score_round_async
+from .tracing import span
 from .types import (DEAD_WINDOW_EPS, ClearingResult, Commitment, JobSpec,
                     RoundResult, SliceSpec, Variant, Window)
 from .windows import (DeadWindowRegistry, SliceTimeline, WindowPolicy,
@@ -232,6 +233,16 @@ class IterationLog:
     # per-window wis_select loop); "" for a round with no bids
     score_backend: str = ""
     settle_backend: str = ""
+    # the settle's fixed-point work, summed over a backend's replays:
+    # conflict-resolution passes, and windows cleared by WIS (first sweep
+    # plus every re-clear)
+    n_passes: int = 0
+    n_reclears: int = 0
+    # how the round's preparation was obtained ("serial", "spec_hit" or
+    # "spec_filtered"; RoundPrep.source) and the speculative preparations
+    # discarded before it
+    prep: str = "serial"
+    n_spec_discarded: int = 0
 
 
 @dataclass
@@ -298,6 +309,11 @@ class RoundPrep:
     wis_prefetch: Optional[object] = None
     stats_snap: Optional[Dict[str, Tuple[int, int]]] = None  # speculative only
     n_dropped: int = 0  # agents dropped by the bid-collection fault gate
+    # set by the round pipeline: "serial", "spec_hit" (speculation used as
+    # is) or "spec_filtered" (speculation with dead windows dropped), and
+    # the speculative preparations it discarded before this round
+    source: str = "serial"
+    n_spec_discarded: int = 0
 
 
 class JasdaScheduler:
@@ -643,10 +659,11 @@ class JasdaScheduler:
         (jobs.py), so generation itself is replayable.
         """
         self._dead_windows.prune(now)
-        windows = announce_windows(
-            self.slices, now, self.policy.window, exclude=self._dead_windows,
-            demand=self.window_demand,
-        )
+        with span("announce"):
+            windows = announce_windows(
+                self.slices, now, self.policy.window,
+                exclude=self._dead_windows, demand=self.window_demand,
+            )
         if not windows:
             return RoundPrep(now=now, epoch=self._epoch, windows=[])
         return self._build_prep(now, windows, speculative=speculative)
@@ -694,35 +711,36 @@ class JasdaScheduler:
         serial one.  Backoff sleeps are simulated-time no-ops: the round
         deadline is a modeling construct, not a wall-clock wait.
         """
-        gate = self.fault_gate
-        if gate is None:
-            return [list(a.respond(announcement).by_window)
-                    for a in agents], 0
-        cfg = self.config
-        empty: List[Sequence[Variant]] = [() for _ in announcement.windows]
-        bids: List[Sequence[Sequence[Variant]]] = []
-        dropped = 0
-        now = announcement.now
-        for a in agents:
-            def _attempt(k: int, agent=a):
-                gate(agent, now, k)
-                return list(agent.respond(announcement).by_window)
+        with span("bids"):
+            gate = self.fault_gate
+            if gate is None:
+                return [list(a.respond(announcement).by_window)
+                        for a in agents], 0
+            cfg = self.config
+            empty: List[Sequence[Variant]] = [() for _ in announcement.windows]
+            bids: List[Sequence[Sequence[Variant]]] = []
+            dropped = 0
+            now = announcement.now
+            for a in agents:
+                def _attempt(k: int, agent=a):
+                    gate(agent, now, k)
+                    return list(agent.respond(announcement).by_window)
 
-            try:
-                bids.append(retry_with_backoff(
-                    _attempt,
-                    retries=cfg.bid_retries,
-                    base=cfg.bid_backoff_base,
-                    factor=cfg.bid_backoff_factor,
-                    max_delay=cfg.bid_backoff_max,
-                    sleep=lambda _delay: None,
-                    retryable=lambda e: isinstance(e, AgentFault)
-                    and e.retryable,
-                ))
-            except AgentFault:
-                bids.append(list(empty))
-                dropped += 1
-        return bids, dropped
+                try:
+                    bids.append(retry_with_backoff(
+                        _attempt,
+                        retries=cfg.bid_retries,
+                        base=cfg.bid_backoff_base,
+                        factor=cfg.bid_backoff_factor,
+                        max_delay=cfg.bid_backoff_max,
+                        sleep=lambda _delay: None,
+                        retryable=lambda e: isinstance(e, AgentFault)
+                        and e.retryable,
+                    ))
+                except AgentFault:
+                    bids.append(list(empty))
+                    dropped += 1
+            return bids, dropped
 
     def _finalize_prep(self, prep: RoundPrep) -> None:
         """Pool assembly + packing + scoring dispatch for prepared bids.
@@ -730,147 +748,155 @@ class JasdaScheduler:
         Factored out so the pipeline can re-run it after dropping the bids
         of invalidated (suppressed-since-speculation) windows.
         """
-        pool: List[Variant] = []
-        bidders = 0
-        budget: Dict[str, float] = {}
-        for agent, per_window in zip(prep.agents, prep.bids):
-            n = sum(len(vs) for vs in per_window)
-            if n:
-                bidders += 1
-                for vs in per_window:
-                    pool.extend(vs)
-                budget[agent.spec.job_id] = agent.biddable_work
-        prep.pool = pool
-        prep.bidders = bidders
-        prep.budget = budget
-        prep.fit, prep.win_idx, prep.view = assign_bids(prep.windows, pool)
-        prep.handle = None
-        prep.wis_prefetch = None
-        prep.energy = None
-        prep.ages = self.ages.ages(prep.now)
-        if prep.fit:
-            # Step 4a: ONE batched scoring dispatch, left in flight (JAX
-            # async) — the settle half blocks on it; the pipeline overlaps
-            # it with the next round's host work.
-            prep.handle = score_round_async(
-                prep.fit, prep.windows, prep.win_idx,
-                self.policy.scoring,
-                ages=prep.ages,
-                calibrate=self.calibrator.calibrate,
-                impl=self.config.score_impl,
-                recheck_theta=self.policy.recheck_theta,
-                per_agent_theta=self.policy.per_agent_theta,
-                grid_cache=self._grid_cache,
-                view=prep.view,
-                mesh=self.config.mesh,
-                health=self.backend_health,
-            )
-            # ψ_energy (repartition layer): per-bid slice-power feature,
-            # folded into the settled scores on the host.  The Eq. 3 clip
-            # is slack (Σβ ≤ 1, ψ ∈ [0,1]), so the host-side addition is
-            # exactly the batched objective with one more fs column.
-            beta_e = self.policy.scoring.betas.get("energy", 0.0)
-            if self.energy_model is not None and beta_e > 0.0:
-                lam = self.policy.scoring.lam
-                psi = np.array(
-                    [self.energy_model.psi(v.slice_id) for v in prep.fit],
-                    np.float64)
-                prep.energy = (1.0 - lam) * beta_e * psi
-            # Step 4a': fused score→clear — with a device wis_impl the
-            # ban-free first WIS pass is dispatched right behind the
-            # scoring call, consuming the still-in-flight device scores.
-            # Settle (and, pipelined, the next round's host prep) then
-            # overlaps the whole score+clear chain instead of just scoring.
-            # The energy adjustment lands AFTER the device dispatch, so the
-            # prefetch (which would clear on pre-adjustment scores) is
-            # skipped whenever the term is active.
-            if prep.energy is None:
-                from .wis import predispatch_settle
+        with span("pack"):
+            pool: List[Variant] = []
+            bidders = 0
+            budget: Dict[str, float] = {}
+            for agent, per_window in zip(prep.agents, prep.bids):
+                n = sum(len(vs) for vs in per_window)
+                if n:
+                    bidders += 1
+                    for vs in per_window:
+                        pool.extend(vs)
+                    budget[agent.spec.job_id] = agent.biddable_work
+            prep.pool = pool
+            prep.bidders = bidders
+            prep.budget = budget
+            prep.fit, prep.win_idx, prep.view = assign_bids(prep.windows, pool)
+            prep.handle = None
+            prep.wis_prefetch = None
+            prep.energy = None
+            prep.ages = self.ages.ages(prep.now)
+            if prep.fit:
+                # Step 4a: ONE batched scoring dispatch, left in flight (JAX
+                # async) — the settle half blocks on it; the pipeline overlaps
+                # it with the next round's host work.
+                prep.handle = score_round_async(
+                    prep.fit, prep.windows, prep.win_idx,
+                    self.policy.scoring,
+                    ages=prep.ages,
+                    calibrate=self.calibrator.calibrate,
+                    impl=self.config.score_impl,
+                    recheck_theta=self.policy.recheck_theta,
+                    per_agent_theta=self.policy.per_agent_theta,
+                    grid_cache=self._grid_cache,
+                    view=prep.view,
+                    mesh=self.config.mesh,
+                    health=self.backend_health,
+                )
+                # ψ_energy (repartition layer): per-bid slice-power feature,
+                # folded into the settled scores on the host.  The Eq. 3 clip
+                # is slack (Σβ ≤ 1, ψ ∈ [0,1]), so the host-side addition is
+                # exactly the batched objective with one more fs column.
+                beta_e = self.policy.scoring.betas.get("energy", 0.0)
+                if self.energy_model is not None and beta_e > 0.0:
+                    lam = self.policy.scoring.lam
+                    psi = np.array(
+                        [self.energy_model.psi(v.slice_id) for v in prep.fit],
+                        np.float64)
+                    prep.energy = (1.0 - lam) * beta_e * psi
+                # Step 4a': fused score→clear — with a device wis_impl the
+                # ban-free first WIS pass is dispatched right behind the
+                # scoring call, consuming the still-in-flight device scores.
+                # Settle (and, pipelined, the next round's host prep) then
+                # overlaps the whole score+clear chain instead of just scoring.
+                # The energy adjustment lands AFTER the device dispatch, so the
+                # prefetch (which would clear on pre-adjustment scores) is
+                # skipped whenever the term is active.
+                if prep.energy is None:
+                    from .wis import predispatch_settle
 
-                prep.wis_prefetch = predispatch_settle(
-                    self._wis_selector, self.policy.clearing,
-                    len(prep.windows), prep.win_idx, prep.view, prep.handle,
-                    ages=prep.ages)
+                    prep.wis_prefetch = predispatch_settle(
+                        self._wis_selector, self.policy.clearing,
+                        len(prep.windows), prep.win_idx, prep.view, prep.handle,
+                        ages=prep.ages)
 
     # -- settle half: block on scores, clear, commit ---------------------------
     def _settle_round(self, prep: RoundPrep) -> Optional[RoundResult]:
-        if not prep.windows:
-            self._append_log(IterationLog(prep.now, None, 0, 0, 0, 0.0))
-            return None
-        scores = prep.handle.result() if prep.handle is not None else np.zeros(0)
-        if prep.energy is not None:
-            scores = scores + prep.energy
-        # Step 4b: selection + conflict resolution, dispatched through the
-        # configured clearing backend (Policy.clearing; GreedyWIS default)
-        # with the configured WIS selector; the fused first-pass prefetch is
-        # forwarded only to backends that declare support for it (custom
-        # backends with the original settle signature stay compatible).
-        kw = {}
-        if (prep.wis_prefetch is not None
-                and getattr(self.policy.clearing, "supports_prefetch", False)):
-            kw["prefetch"] = prep.wis_prefetch
-        rr = self.policy.clearing.settle(
-            prep.windows, prep.fit, prep.win_idx, scores,
-            selector=self._wis_selector,
-            work_budget=prep.budget, view=prep.view, ages=prep.ages,
-            **kw,
-        )
-
-        # Step 5: commit winners; suppress windows that cleared empty.
-        now = prep.now
-        for result in rr.results:
-            if result.selected:
-                tl = self.slices[result.window.slice_id]
-                for v, s in zip(result.selected, result.scores):
-                    tl.commit(v.t_start, v.t_end)
-                    self._record_commit(v, now, s)
-                    self.ages.mark_selected(v.job_id, now)
-                    agent = self.agents[v.job_id]
-                    agent.n_wins += 1
-                    agent.score_won += float(s)
-                    agent.mark_committed(v)
-            else:
-                self._dead_windows.add(
-                    result.window.slice_id,
-                    result.window.t_min,
-                    now + self.config.dead_window_cooldown,
+        with span("settle"):
+            if not prep.windows:
+                self._append_log(IterationLog(
+                    prep.now, None, 0, 0, 0, 0.0, prep=prep.source,
+                    n_spec_discarded=prep.n_spec_discarded))
+                return None
+            scores = prep.handle.result() if prep.handle is not None else np.zeros(0)
+            if prep.energy is not None:
+                scores = scores + prep.energy
+            # Step 4b: selection + conflict resolution, dispatched through the
+            # configured clearing backend (Policy.clearing; GreedyWIS default)
+            # with the configured WIS selector; the fused first-pass prefetch is
+            # forwarded only to backends that declare support for it (custom
+            # backends with the original settle signature stay compatible).
+            kw = {}
+            if (prep.wis_prefetch is not None
+                    and getattr(self.policy.clearing, "supports_prefetch", False)):
+                kw["prefetch"] = prep.wis_prefetch
+            with span("settle.clear"):
+                rr = self.policy.clearing.settle(
+                    prep.windows, prep.fit, prep.win_idx, scores,
+                    selector=self._wis_selector,
+                    work_budget=prep.budget, view=prep.view, ages=prep.ages,
+                    **kw,
                 )
-        # The clearing→agent feedback channel (the negotiation loop's
-        # closing leg): publish one RoundFeedback broadcast — per-window
-        # winning-score cutoffs, per-job awards/losses with reasons, and the
-        # §4.2.1 calibration state — to every agent of the round.  A
-        # strategy that adapts (observe_feedback → True) could bid
-        # differently next round, so it invalidates speculative
-        # preparations exactly like a state mutation: epoch-validated, the
-        # same protocol that guards dead windows (core/pipeline.py).
-        feedback = build_feedback(
-            now, prep.windows, prep.agents, prep.bids, rr, self.calibrator,
-            view=prep.view, win_idx=prep.win_idx,
-        )
-        adapted = False
-        for agent in prep.agents:
-            if agent.observe_feedback(feedback):
-                adapted = True
-        self.last_feedback = feedback
 
-        if rr.selected or adapted:
-            # timelines, agent budgets, ages or strategy state changed:
-            # invalidate any speculative preparation built against the
-            # pre-settle state
-            self._epoch += 1
+            # Step 5: commit winners; suppress windows that cleared empty.
+            now = prep.now
+            for result in rr.results:
+                if result.selected:
+                    tl = self.slices[result.window.slice_id]
+                    for v, s in zip(result.selected, result.scores):
+                        tl.commit(v.t_start, v.t_end)
+                        self._record_commit(v, now, s)
+                        self.ages.mark_selected(v.job_id, now)
+                        agent = self.agents[v.job_id]
+                        agent.n_wins += 1
+                        agent.score_won += float(s)
+                        agent.mark_committed(v)
+                else:
+                    self._dead_windows.add(
+                        result.window.slice_id,
+                        result.window.t_min,
+                        now + self.config.dead_window_cooldown,
+                    )
+            # The clearing→agent feedback channel (the negotiation loop's
+            # closing leg): publish one RoundFeedback broadcast — per-window
+            # winning-score cutoffs, per-job awards/losses with reasons, and the
+            # §4.2.1 calibration state — to every agent of the round.  A
+            # strategy that adapts (observe_feedback → True) could bid
+            # differently next round, so it invalidates speculative
+            # preparations exactly like a state mutation: epoch-validated, the
+            # same protocol that guards dead windows (core/pipeline.py).
+            with span("settle.feedback"):
+                feedback = build_feedback(
+                    now, prep.windows, prep.agents, prep.bids, rr,
+                    self.calibrator, view=prep.view, win_idx=prep.win_idx,
+                )
+                adapted = False
+                for agent in prep.agents:
+                    if agent.observe_feedback(feedback):
+                        adapted = True
+            self.last_feedback = feedback
 
-        rr.n_bidders = prep.bidders
-        self._append_log(
-            IterationLog(
-                now, prep.windows[0], prep.bidders, rr.n_bids, len(rr.selected),
-                rr.total_score, n_windows=len(prep.windows),
-                n_conflicts=rr.n_conflicts, n_dropped=prep.n_dropped,
-                score_backend=(prep.handle.backend
-                               if prep.handle is not None else ""),
-                settle_backend=self.settle_backend if prep.fit else "",
+            if rr.selected or adapted:
+                # timelines, agent budgets, ages or strategy state changed:
+                # invalidate any speculative preparation built against the
+                # pre-settle state
+                self._epoch += 1
+
+            rr.n_bidders = prep.bidders
+            self._append_log(
+                IterationLog(
+                    now, prep.windows[0], prep.bidders, rr.n_bids, len(rr.selected),
+                    rr.total_score, n_windows=len(prep.windows),
+                    n_conflicts=rr.n_conflicts, n_dropped=prep.n_dropped,
+                    score_backend=(prep.handle.backend
+                                   if prep.handle is not None else ""),
+                    settle_backend=self.settle_backend if prep.fit else "",
+                    n_passes=rr.n_passes, n_reclears=rr.n_reclears,
+                    prep=prep.source, n_spec_discarded=prep.n_spec_discarded,
+                )
             )
-        )
-        return rr
+            return rr
 
     # -- bounded bookkeeping ---------------------------------------------------
     def _record_commit(self, v: Variant, now: float, score: float) -> None:

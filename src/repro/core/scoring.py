@@ -25,6 +25,7 @@ from typing import Callable, Dict, Mapping, Optional, Sequence
 
 import numpy as np
 
+from .tracing import span
 from .types import Variant, Window
 
 __all__ = [
@@ -291,7 +292,8 @@ class ScoreHandle:
     def result(self) -> np.ndarray:
         if not isinstance(self._scores, np.ndarray):
             # np.asarray on a jax array blocks until the computation lands
-            arr = np.asarray(self._scores, dtype=np.float64)
+            with span("score.wait"):
+                arr = np.asarray(self._scores, dtype=np.float64)
             self._scores = arr[: self._m] if self._m is not None else arr
         return self._scores
 
@@ -325,94 +327,95 @@ def score_round_async(
     of the device dispatch across devices — byte-identical scores, ignored
     by the host numpy path.
     """
-    m = len(variants)
-    if m == 0:
-        return ScoreHandle(np.zeros(0, dtype=np.float64))
-    # lazy import: keeps the numpy-only control plane importable without jax
-    from ..kernels.jasda_score.ops import pool_to_arrays_round
+    with span("score.dispatch"):
+        m = len(variants)
+        if m == 0:
+            return ScoreHandle(np.zeros(0, dtype=np.float64))
+        # lazy import: keeps the numpy-only control plane importable without jax
+        from ..kernels.jasda_score.ops import pool_to_arrays_round
 
-    if calibrate is None and view is not None:
-        h = view.local_utility  # already a float64 column; no python walk
-    else:
-        h = np.empty(m, dtype=np.float64)
-        for i, v in enumerate(variants):
-            h[i] = calibrate(v, v.local_utility) if calibrate is not None else v.local_utility
-    # θ precedence: a scheduler-wide recheck_theta overrides the per-agent
-    # bounds; per_agent_theta alone gathers each bid's OWN declared θ
-    # (Variant.theta, set from AgentConfig.theta at generation) into
-    # PackedRound.thetas so heterogeneous agents recheck heterogeneously.
-    recheck = recheck_theta is not None or per_agent_theta
-    if recheck_theta is not None:
-        theta = recheck_theta
-    elif per_agent_theta:
-        theta = (view.thetas if view is not None
-                 else np.asarray([v.theta for v in variants], np.float64))
-    else:
-        theta = 1.0
-    packed = pool_to_arrays_round(
-        variants, windows, np.asarray(win_idx), policy,
-        h=h, ages=ages, grid=grid, pack_grids=recheck,
-        theta=theta, cache=grid_cache,
-        view=view,
-    )
-    def _numpy_scores() -> np.ndarray:
-        # host float64 reference: the small-pool fast path and the last
-        # rung of the injected-fault ladder.  Ranks match the legacy
-        # per-window path.
-        if recheck:
-            from ..kernels.jasda_score.ops import score_variants_numpy
+        if calibrate is None and view is not None:
+            h = view.local_utility  # already a float64 column; no python walk
+        else:
+            h = np.empty(m, dtype=np.float64)
+            for i, v in enumerate(variants):
+                h[i] = calibrate(v, v.local_utility) if calibrate is not None else v.local_utility
+        # θ precedence: a scheduler-wide recheck_theta overrides the per-agent
+        # bounds; per_agent_theta alone gathers each bid's OWN declared θ
+        # (Variant.theta, set from AgentConfig.theta at generation) into
+        # PackedRound.thetas so heterogeneous agents recheck heterogeneously.
+        recheck = recheck_theta is not None or per_agent_theta
+        if recheck_theta is not None:
+            theta = recheck_theta
+        elif per_agent_theta:
+            theta = (view.thetas if view is not None
+                     else np.asarray([v.theta for v in variants], np.float64))
+        else:
+            theta = 1.0
+        packed = pool_to_arrays_round(
+            variants, windows, np.asarray(win_idx), policy,
+            h=h, ages=ages, grid=grid, pack_grids=recheck,
+            theta=theta, cache=grid_cache,
+            view=view,
+        )
+        def _numpy_scores() -> np.ndarray:
+            # host float64 reference: the small-pool fast path and the last
+            # rung of the injected-fault ladder.  Ranks match the legacy
+            # per-window path.
+            if recheck:
+                from ..kernels.jasda_score.ops import score_variants_numpy
 
-            scores, _, _ = score_variants_numpy(
-                packed.fj, packed.fs, packed.alphas, packed.betas,
-                packed.mu, packed.sg,
-                lam=policy.lam, capacity=packed.caps, theta=packed.thetas,
-            )
-            return np.asarray(scores, np.float64)
-        hh = np.clip(packed.fj @ packed.alphas, 0.0, 1.0)
-        ff = np.clip(packed.fs @ packed.betas, 0.0, 1.0)
-        return policy.lam * hh + (1.0 - policy.lam) * ff
+                scores, _, _ = score_variants_numpy(
+                    packed.fj, packed.fs, packed.alphas, packed.betas,
+                    packed.mu, packed.sg,
+                    lam=policy.lam, capacity=packed.caps, theta=packed.thetas,
+                )
+                return np.asarray(scores, np.float64)
+            hh = np.clip(packed.fj @ packed.alphas, 0.0, 1.0)
+            ff = np.clip(packed.fs @ packed.betas, 0.0, 1.0)
+            return policy.lam * hh + (1.0 - policy.lam) * ff
 
-    if impl is None and m < SMALL_POOL_M:
-        # device-dispatch overhead dominates tiny pools; same math on host
-        impl = "numpy"
-    if impl is None:
-        import jax
+        if impl is None and m < SMALL_POOL_M:
+            # device-dispatch overhead dominates tiny pools; same math on host
+            impl = "numpy"
+        if impl is None:
+            import jax
 
-        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
-    # sticky injected faults steer the choice down the ladder
-    dev_impl = health.resolve(impl) if health is not None else impl
-    if dev_impl == "numpy":
-        return ScoreHandle(_numpy_scores())
+            impl = "pallas" if jax.default_backend() == "tpu" else "ref"
+        # sticky injected faults steer the choice down the ladder
+        dev_impl = health.resolve(impl) if health is not None else impl
+        if dev_impl == "numpy":
+            return ScoreHandle(_numpy_scores())
 
-    from ..kernels.common import KernelDispatchError
-    from ..kernels.jasda_score.ops import score_variants
+        from ..kernels.common import KernelDispatchError
+        from ..kernels.jasda_score.ops import score_variants
 
-    # trim=False keeps the bucket-padded device array on the handle: the
-    # fused settle dispatch gathers weights from it shape-stably (padded
-    # rows are self-masking, and result() slices back to m on the host).
-    # With a BackendHealth attached an injected fault walks the
-    # degradation ladder: the faulted backend is marked sick (sticky) and
-    # the round re-dispatches one rung down, bottoming out at host numpy.
-    while True:
-        try:
-            scores, _, _ = score_variants(
-                packed.fj, packed.fs, packed.alphas, packed.betas,
-                packed.mu, packed.sg,
-                lam=policy.lam,
-                capacity=packed.caps if recheck else 1.0,
-                theta=packed.thetas if recheck else 1.0,
-                impl=dev_impl,
-                trim=False,
-                mesh=mesh,
-            )
-            return ScoreHandle(scores, m=m, backend=dev_impl)
-        except KernelDispatchError as exc:
-            if health is None:
-                raise
-            health.mark_failed(exc.backend, str(exc))
-            dev_impl = health.resolve(exc.backend)
-            if dev_impl == "numpy":
-                return ScoreHandle(_numpy_scores())
+        # trim=False keeps the bucket-padded device array on the handle: the
+        # fused settle dispatch gathers weights from it shape-stably (padded
+        # rows are self-masking, and result() slices back to m on the host).
+        # With a BackendHealth attached an injected fault walks the
+        # degradation ladder: the faulted backend is marked sick (sticky) and
+        # the round re-dispatches one rung down, bottoming out at host numpy.
+        while True:
+            try:
+                scores, _, _ = score_variants(
+                    packed.fj, packed.fs, packed.alphas, packed.betas,
+                    packed.mu, packed.sg,
+                    lam=policy.lam,
+                    capacity=packed.caps if recheck else 1.0,
+                    theta=packed.thetas if recheck else 1.0,
+                    impl=dev_impl,
+                    trim=False,
+                    mesh=mesh,
+                )
+                return ScoreHandle(scores, m=m, backend=dev_impl)
+            except KernelDispatchError as exc:
+                if health is None:
+                    raise
+                health.mark_failed(exc.backend, str(exc))
+                dev_impl = health.resolve(exc.backend)
+                if dev_impl == "numpy":
+                    return ScoreHandle(_numpy_scores())
 
 
 def score_round(

@@ -252,6 +252,11 @@ class RoundResult:
     # columns without re-identifying variant objects.  Backends that do not
     # track pool indices may leave it empty (callers must fall back).
     selected_idx: Sequence = ()
+    # the settle's fixed-point work (policy/base.py ``_FixedPointState``),
+    # summed over a backend's replays: conflict-resolution passes, and
+    # windows cleared by WIS (first sweep plus every re-clear)
+    n_passes: int = 0
+    n_reclears: int = 0
 
 
 # ---------------------------------------------------------------------------

@@ -40,6 +40,7 @@ from ..core.events import (ARRIVE, CANCEL, COMPLETE, DEADLINE, REPARTITION,
                            TICK, EventHeap, ExecutionPlumbing)
 from ..core.jobs import AgentConfig, JobAgent
 from ..core.negotiation.messages import build_shed_feedback
+from ..core.tracing import span
 from ..core.types import SliceSpec
 from ..runtime.monitor import HealthConfig, HealthMonitor
 from .admission import AcceptAll, AdmissionPolicy, BoundedQueue, \
@@ -239,45 +240,46 @@ class JasdaService:
 
     # -- event handlers ----------------------------------------------------
     def _on_tick(self, now: float, horizon: float, pipe) -> None:
-        cfg = self.cfg
-        # stage the next round-interval of arrivals so they interleave
-        # with this heap (an arrival at t ∈ (now, now+dt] pops before the
-        # tick at now+dt: ARRIVE orders before TICK at equal timestamps)
-        for ev in self.arrivals.take_until(min(now + cfg.round_dt, horizon)):
-            if isinstance(ev, JobArrival):
-                self.heap.push(ev.t, ARRIVE, ev)
-            elif isinstance(ev, JobCancel):
-                self.heap.push(ev.t, CANCEL, ev)
-            elif isinstance(ev, DeadlineExpired):
-                self.heap.push(ev.t, DEADLINE, ev)
-        # health: heartbeat live slices (muted ones go silent), then police
-        for sid in self.scheduler.slices:
-            if sid not in self._muted:
-                self.monitor.heartbeat(sid, now)
-        self._police_slices(now)
-        # the auction round (pipelined prepare/settle when available)
-        self.metrics.n_rounds += 1
-        self.round_count += 1
-        nxt = now + cfg.round_dt
-        if pipe is not None:
-            rr = pipe.tick(now, next_time=nxt if nxt <= horizon else None)
-        else:
-            rr = self.scheduler.run_round(now)
-        if rr is not None:
-            # every live job saw this announcement; first-seen is the
-            # announce timestamp of its decision path
-            for job_id in self.scheduler.agents:
-                self.metrics.announced(job_id, now)
-            for v in rr.selected:
-                self.metrics.awarded(v.job_id, now)
-                if cfg.keep_award_log:
-                    self.award_log.append(AwardRecord(
-                        self.round_count, now, v.variant_id, v.job_id,
-                        v.slice_id))
-            self.exec.pending.extend(rr.selected)
-        self.exec.launch_due(now, cfg.round_dt, self.dead_slices)
-        if nxt <= horizon:
-            self.heap.push(nxt, TICK)
+        with span("round", round=self.round_count + 1):
+            cfg = self.cfg
+            # stage the next round-interval of arrivals so they interleave
+            # with this heap (an arrival at t ∈ (now, now+dt] pops before the
+            # tick at now+dt: ARRIVE orders before TICK at equal timestamps)
+            for ev in self.arrivals.take_until(min(now + cfg.round_dt, horizon)):
+                if isinstance(ev, JobArrival):
+                    self.heap.push(ev.t, ARRIVE, ev)
+                elif isinstance(ev, JobCancel):
+                    self.heap.push(ev.t, CANCEL, ev)
+                elif isinstance(ev, DeadlineExpired):
+                    self.heap.push(ev.t, DEADLINE, ev)
+            # health: heartbeat live slices (muted ones go silent), then police
+            for sid in self.scheduler.slices:
+                if sid not in self._muted:
+                    self.monitor.heartbeat(sid, now)
+            self._police_slices(now)
+            # the auction round (pipelined prepare/settle when available)
+            self.metrics.n_rounds += 1
+            self.round_count += 1
+            nxt = now + cfg.round_dt
+            if pipe is not None:
+                rr = pipe.tick(now, next_time=nxt if nxt <= horizon else None)
+            else:
+                rr = self.scheduler.run_round(now)
+            if rr is not None:
+                # every live job saw this announcement; first-seen is the
+                # announce timestamp of its decision path
+                for job_id in self.scheduler.agents:
+                    self.metrics.announced(job_id, now)
+                for v in rr.selected:
+                    self.metrics.awarded(v.job_id, now)
+                    if cfg.keep_award_log:
+                        self.award_log.append(AwardRecord(
+                            self.round_count, now, v.variant_id, v.job_id,
+                            v.slice_id))
+                self.exec.pending.extend(rr.selected)
+            self.exec.launch_due(now, cfg.round_dt, self.dead_slices)
+            if nxt <= horizon:
+                self.heap.push(nxt, TICK)
 
     def _on_repartition(self, now: float, horizon: float) -> None:
         """Between-rounds repartition opportunity (periodic heap event).

@@ -298,7 +298,7 @@ def test_pipeline_filter_path_matches_fresh_preparation():
         s._dead_windows.add(dead.slice_id, dead.t_min, expiry=100.0)
     pipe._spec = spec
     prep = pipe._take_validated(2.0)
-    assert prep is not None and pipe.stats["spec_filtered"] == 1
+    assert prep is not None and prep.source == "spec_filtered"
     fresh = fresh_s._prepare_round(2.0)
     assert [(w.slice_id, w.t_min) for w in prep.windows] == \
         [(w.slice_id, w.t_min) for w in fresh.windows]
@@ -306,10 +306,16 @@ def test_pipeline_filter_path_matches_fresh_preparation():
         [v.variant_id for v in fresh.pool]
     assert ({j: a.n_bids for j, a in spec_s.agents.items()}
             == {j: a.n_bids for j, a in fresh_s.agents.items()})
+    # the round's audit row records where its preparation came from
+    spec_s._settle_round(prep)
+    fresh_s._settle_round(fresh)
+    assert (spec_s.log[-1].prep, spec_s.log[-1].n_spec_discarded) == \
+        ("spec_filtered", 0)
+    assert fresh_s.log[-1].prep == "serial"
 
 
 def test_pipeline_discard_restores_bid_stats():
-    sched = _mk_sched(n_jobs=10)
+    sched, fresh = _mk_sched(n_jobs=10), _mk_sched(n_jobs=10)
     before = {j: a.n_bids for j, a in sched.agents.items()}
     pipe = RoundPipeline(sched)
     spec = sched._prepare_round(2.0, speculative=True)
@@ -318,7 +324,16 @@ def test_pipeline_discard_restores_bid_stats():
     sched._epoch += 1  # any state mutation invalidates the speculation
     assert pipe._take_validated(2.0) is None
     assert {j: a.n_bids for j, a in sched.agents.items()} == before
-    assert pipe.stats["spec_discarded"] == 1
+    # through a tick: the discard is counted on the serial round's row,
+    # and the bid statistics equal those of one never-speculated round
+    pipe._spec = sched._prepare_round(2.0, speculative=True)
+    sched._epoch += 1
+    pipe.tick(2.0)
+    fresh.run_round(2.0)
+    row = sched.log[-1]
+    assert (row.prep, row.n_spec_discarded) == ("serial", 1)
+    assert ({j: a.n_bids for j, a in sched.agents.items()}
+            == {j: a.n_bids for j, a in fresh.agents.items()})
 
 
 def test_pipelined_clear_rounds_identical_selections():
