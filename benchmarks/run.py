@@ -921,7 +921,7 @@ def bench_settle_throughput():
         scores = rng.integers(1, 1 << 12, len(fit)).astype(np.float64) / (1 << 12)
         return windows, fit, win_idx, view, scores
 
-    host = make_round_selector(None)
+    host = wis_select
     batched = make_round_selector("numpy")
     device = make_round_selector(device_impl)
     reps = 5 if QUICK else 7

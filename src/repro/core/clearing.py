@@ -39,7 +39,8 @@ import numpy as np
 from .scoring import ScoringPolicy, score_pool, score_round_async
 from .types import (OVERLAP_EPS, TIME_EPS, ClearingResult, PoolView,
                     RoundResult, Variant, Window)
-from .wis import make_round_selector, predispatch_settle, wis_select
+from .wis import (HOST_SELECTOR, make_round_selector, predispatch_settle,
+                  wis_select)
 
 __all__ = ["clear_window", "clear_round", "assign_bids", "settle_round"]
 
@@ -168,7 +169,7 @@ def clear_round(
     *,
     ages: Optional[Mapping[str, float]] = None,
     calibrate: Optional[Callable[[Variant, float], float]] = None,
-    selector: Callable = wis_select,
+    selector: Callable = HOST_SELECTOR,
     work_budget: Optional[Mapping[str, float]] = None,
     score_impl: Optional[str] = None,
     recheck_theta: Optional[float] = None,
@@ -197,10 +198,11 @@ def clear_round(
     :func:`settle_round`) so the round pipeline can overlap them across
     consecutive rounds.
 
+    ``selector`` defaults to the batched host settle; pass
+    :func:`~repro.core.wis.wis_select` for the plain per-window reference.
     ``wis_impl`` selects the settle-side WIS backend (overrides
-    ``selector``): None = the per-window host loop, "numpy" = batched host
-    float64, "ref"/"pallas" = the device-resident batched settle
-    (``kernels/wis_dp``).  With a device backend the ban-free first WIS
+    ``selector``): "numpy" = the batched host settle, "ref"/"pallas" = the
+    device-resident batched settle (``kernels/wis_dp``).  With a device backend the ban-free first WIS
     pass is FUSED behind the scoring dispatch — selection weights are
     gathered from the still-in-flight device scores, no host round-trip.
 
@@ -248,7 +250,7 @@ def settle_round(
     win_idx: Sequence[int],
     scores: np.ndarray,
     *,
-    selector: Callable = wis_select,
+    selector: Callable = HOST_SELECTOR,
     work_budget: Optional[Mapping[str, float]] = None,
     view: Optional[PoolView] = None,
     clearing=None,

@@ -48,7 +48,7 @@ from typing import Callable, Dict, List, Mapping, Optional, Sequence, Tuple
 import numpy as np
 
 from ..types import PoolView, RoundResult, Variant, Window
-from ..wis import RoundSelector, wis_select
+from ..wis import HOST_SELECTOR, RoundSelector
 from .base import (ClearingPolicy, _FixedPointState, _pool_members,
                    fixed_point_settle)
 
@@ -79,7 +79,7 @@ class GlobalAssignment(ClearingPolicy):
         win_idx: Sequence[int],
         scores: np.ndarray,
         *,
-        selector: Callable = wis_select,
+        selector: Callable = HOST_SELECTOR,
         work_budget: Optional[Mapping[str, float]] = None,
         view: Optional[PoolView] = None,
         ages: Optional[Mapping[str, float]] = None,
@@ -114,6 +114,7 @@ class GlobalAssignment(ClearingPolicy):
         evals = 0
         # fixed-point work of every replay, added to the greedy baseline's
         passes, reclears = best.n_passes, best.n_reclears
+        reclears_vec = best.n_reclears_vec
         members = packed.members if packed is not None else _pool_members(
             len(windows), win_idx)
         # replays compare on cheap state TOTALS (identical float-sum order
@@ -130,7 +131,7 @@ class GlobalAssignment(ClearingPolicy):
             return prefer
 
         def run_state(choice) -> _FixedPointState:
-            nonlocal passes, reclears
+            nonlocal passes, reclears, reclears_vec
             st = _FixedPointState(windows, fit, win_idx, scores, view,
                                   members, selector, packed, work_budget,
                                   to_prefer(choice))
@@ -138,6 +139,7 @@ class GlobalAssignment(ClearingPolicy):
             st.run_to_fixed_point()
             passes += st.n_passes
             reclears += st.n_reclears
+            reclears_vec += st.n_reclears_vec
             return st
 
         def evaluate(choice: Sequence[Optional[int]]) -> bool:
@@ -160,6 +162,7 @@ class GlobalAssignment(ClearingPolicy):
         def finish() -> RoundResult:
             rr = best_state.package(scores) if best_state is not None else best
             rr.n_passes, rr.n_reclears = passes, reclears
+            rr.n_reclears_vec = reclears_vec
             return rr
 
         n_joint = 1
@@ -236,6 +239,9 @@ class GlobalAssignment(ClearingPolicy):
                     owners.append((st, k))
             if not requests:
                 break
+            if rs.vectorizes(packed, [k for k, _ in requests]):
+                for st, _ in owners:
+                    st.n_reclears_vec += 1
             for (st, k), sel in zip(owners, rs.select_rows(packed, requests)):
                 st.selected[k] = sel
             for st in states:
@@ -251,6 +257,8 @@ class GlobalAssignment(ClearingPolicy):
         rr = best_state.package(scores) if best_state is not None else best
         rr.n_passes = best.n_passes + sum(st.n_passes for st in states)
         rr.n_reclears = best.n_reclears + sum(st.n_reclears for st in states)
+        rr.n_reclears_vec = (best.n_reclears_vec
+                             + sum(st.n_reclears_vec for st in states))
         return rr
 
     # -- conflict structure ---------------------------------------------------

@@ -41,7 +41,7 @@ from typing import Callable, Dict, List, Mapping, Optional, Sequence
 import numpy as np
 
 from ..types import PoolView, RoundResult, Variant, Window
-from ..wis import RoundSelector, SettlePrefetch, wis_select
+from ..wis import HOST_SELECTOR, RoundSelector, SettlePrefetch
 
 __all__ = ["ClearingPolicy", "fixed_point_settle"]
 
@@ -86,7 +86,7 @@ class ClearingPolicy(abc.ABC):
         win_idx: Sequence[int],
         scores: np.ndarray,
         *,
-        selector: Callable = wis_select,
+        selector: Callable = HOST_SELECTOR,
         work_budget: Optional[Mapping[str, float]] = None,
         view: Optional[PoolView] = None,
         ages: Optional[Mapping[str, float]] = None,
@@ -96,6 +96,9 @@ class ClearingPolicy(abc.ABC):
         ``scores`` are the auction scores reported in the result (commit
         scores, totals); a backend may SELECT on a transformed copy but must
         report the raw values so totals stay comparable across backends.
+        ``selector`` defaults to the batched host settle
+        (``core.wis.HOST_SELECTOR``); pass ``core.wis.wis_select`` for the
+        plain per-window reference loop.
         ``ages`` maps job_id → A_i(t) ∈ [0,1] for fairness-aware backends.
         """
 
@@ -169,9 +172,11 @@ class _FixedPointState:
         self.selected: List[List[int]] = [[] for _ in windows]
         self.dirty: List[int] = list(range(len(windows)))
         self.n_conflicts = 0
-        # work counters: resolve passes, and windows cleared by WIS
+        # work counters: resolve passes, windows cleared by WIS, and of
+        # those the windows cleared by a batched DP (RoundSelector.vectorizes)
         self.n_passes = 0
         self.n_reclears = 0
+        self.n_reclears_vec = 0
         self.active = True  # False once the fixed point is reached
 
     def seed(self, first_pass: Sequence[Sequence[int]]) -> None:
@@ -189,9 +194,12 @@ class _FixedPointState:
             return
         self.n_reclears += len(ks)
         if self.rs is not None:
+            if self.rs.vectorizes(self.packed, ks):
+                self.n_reclears_vec += len(ks)
             for k, sel in zip(ks, self.rs.select(self.packed, ks, self.banned)):
                 self.selected[k] = sel
             return
+        # the plain per-window reference: a selector callable passed in
         view, sel_scores = self.view, self.sel_scores
         for k in ks:
             idx = [i for i in self.members[k] if not self.banned[i]]
@@ -317,6 +325,7 @@ class _FixedPointState:
             selected_idx=tuple(selected_idx),
             n_passes=self.n_passes,
             n_reclears=self.n_reclears,
+            n_reclears_vec=self.n_reclears_vec,
         )
 
 
@@ -326,7 +335,7 @@ def fixed_point_settle(
     win_idx: Sequence[int],
     scores: np.ndarray,
     *,
-    selector: Callable = wis_select,
+    selector: Callable = HOST_SELECTOR,
     work_budget: Optional[Mapping[str, float]] = None,
     view: Optional[PoolView] = None,
     select_scores: Optional[np.ndarray] = None,
@@ -346,11 +355,12 @@ def fixed_point_settle(
     lose a winner are re-cleared within the round; bans grow monotonically,
     so the loop reaches a fixed point in ≤ |pool| passes.
 
-    ``selector`` is either the classic per-window callable (default
-    :func:`wis_select`) or a batched :class:`~repro.core.wis.RoundSelector`
-    — then every sweep (the ban-free first pass AND each conflict
-    re-clear) dispatches ALL its dirty windows at once from retained packed
-    buffers instead of looping windows on the host.
+    ``selector`` is a batched :class:`~repro.core.wis.RoundSelector`
+    (default: the host one, ``HOST_SELECTOR``) — every sweep (the ban-free
+    first pass AND each conflict re-clear) then clears ALL its dirty
+    windows at once from retained packed buffers, sorted once a round — or
+    a classic per-window callable such as :func:`~repro.core.wis.
+    wis_select`, the plain reference that loops windows on the host.
 
     Hooks the backends compose:
 
@@ -404,8 +414,9 @@ def fixed_point_settle(
                           selector, packed, work_budget, prefer)
     if first_pass is not None:
         st.seed(first_pass)
-        # the adopted first pass cleared every window once
-        st.n_reclears = len(windows)
+        # the adopted first pass cleared every window once, in one batched
+        # (fused device) dispatch
+        st.n_reclears = st.n_reclears_vec = len(windows)
 
     # fixed point: each pass bans ≥ 1 variant or terminates, so the loop is
     # bounded by the pool size

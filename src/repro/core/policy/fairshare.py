@@ -28,7 +28,7 @@ from typing import Callable, Mapping, Optional, Sequence
 import numpy as np
 
 from ..types import PoolView, RoundResult, Variant, Window
-from ..wis import wis_select
+from ..wis import HOST_SELECTOR
 from .base import ClearingPolicy, fixed_point_settle
 
 __all__ = ["FairShare"]
@@ -78,7 +78,7 @@ class FairShare(ClearingPolicy):
         win_idx: Sequence[int],
         scores: np.ndarray,
         *,
-        selector: Callable = wis_select,
+        selector: Callable = HOST_SELECTOR,
         work_budget: Optional[Mapping[str, float]] = None,
         view: Optional[PoolView] = None,
         ages: Optional[Mapping[str, float]] = None,

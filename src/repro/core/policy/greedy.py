@@ -16,7 +16,7 @@ from typing import Callable, Mapping, Optional, Sequence
 import numpy as np
 
 from ..types import PoolView, RoundResult, Variant, Window
-from ..wis import wis_select
+from ..wis import HOST_SELECTOR
 from .base import ClearingPolicy, fixed_point_settle
 
 __all__ = ["GreedyWIS"]
@@ -38,7 +38,7 @@ class GreedyWIS(ClearingPolicy):
         win_idx: Sequence[int],
         scores: np.ndarray,
         *,
-        selector: Callable = wis_select,
+        selector: Callable = HOST_SELECTOR,
         work_budget: Optional[Mapping[str, float]] = None,
         view: Optional[PoolView] = None,
         ages: Optional[Mapping[str, float]] = None,

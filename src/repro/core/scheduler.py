@@ -108,10 +108,10 @@ class SchedulerConfig:
     # batched-scoring backend override: None = auto (Pallas on TPU, jnp
     # reference elsewhere); "numpy" | "ref" | "pallas" to force
     score_impl: Optional[str] = None
-    # settle-side WIS backend (the device-resident batched settle): None =
-    # the historical per-window host loop (byte-identical default);
-    # "numpy" = batched host float64 (byte-identical, one DP loop per lane
-    # for all windows); "ref" | "pallas" = kernels/wis_dp device dispatch
+    # settle-side WIS backend: None = "numpy" = the batched host settle
+    # (float64, byte-identical to the per-window wis_select loop: the round
+    # is sorted and packed once, re-clears run from the retained pack);
+    # "ref" | "pallas" = kernels/wis_dp device dispatch
     # with the first WIS pass fused behind the scoring dispatch.  A runtime
     # knob like score_impl — it changes WHERE clearing runs, never what is
     # selected (parity is gated by tests/test_device_settle.py).
@@ -229,15 +229,19 @@ class IterationLog:
     # past the retry budget) — the audit trail of graceful degradation
     n_dropped: int = 0
     # where the round ran: the scoring backend ("numpy" | "ref" | "pallas")
-    # and the settle backend (those three, or "host-loop" for the
-    # per-window wis_select loop); "" for a round with no bids
+    # and the settle backend (those three, or "host-loop" for a per-window
+    # selector callable set in place of the default); "" for a round with
+    # no bids
     score_backend: str = ""
     settle_backend: str = ""
     # the settle's fixed-point work, summed over a backend's replays:
-    # conflict-resolution passes, and windows cleared by WIS (first sweep
-    # plus every re-clear)
+    # conflict-resolution passes, windows cleared by WIS (first sweep plus
+    # every re-clear), and of those the windows cleared by a batched DP
+    # (the lane-major host DP or the device kernel; the rest ran the
+    # per-row scalar DP or a per-window selector)
     n_passes: int = 0
     n_reclears: int = 0
+    n_reclears_vec: int = 0
     # how the round's preparation was obtained ("serial", "spec_hit" or
     # "spec_filtered"; RoundPrep.source) and the speculative preparations
     # discarded before it
@@ -406,8 +410,8 @@ class JasdaScheduler:
         self.window_demand: Optional[Tuple[float, ...]] = None
         self.energy_model = None
         # settle-side WIS backend (SchedulerConfig.wis_impl): the default is
-        # the historical per-window host loop; the batched backends clear
-        # every window of a round in one dispatch (core/wis.py)
+        # the batched host settle; every backend clears all dirty windows of
+        # a sweep in one call from the round's retained pack (core/wis.py)
         from .wis import make_round_selector
 
         self._wis_selector = make_round_selector(self.config.wis_impl,
@@ -416,8 +420,9 @@ class JasdaScheduler:
 
     @property
     def settle_backend(self) -> str:
-        """Where the WIS settle runs now ("host-loop" for the per-window
-        loop); moves down the ladder only after an injected fault."""
+        """Where the WIS settle runs now ("host-loop" for a per-window
+        selector callable); moves down the ladder only after an injected
+        fault."""
         from .wis import RoundSelector
 
         sel = self._wis_selector
@@ -893,6 +898,7 @@ class JasdaScheduler:
                                    if prep.handle is not None else ""),
                     settle_backend=self.settle_backend if prep.fit else "",
                     n_passes=rr.n_passes, n_reclears=rr.n_reclears,
+                    n_reclears_vec=rr.n_reclears_vec,
                     prep=prep.source, n_spec_discarded=prep.n_spec_discarded,
                 )
             )

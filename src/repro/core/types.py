@@ -253,10 +253,12 @@ class RoundResult:
     # track pool indices may leave it empty (callers must fall back).
     selected_idx: Sequence = ()
     # the settle's fixed-point work (policy/base.py ``_FixedPointState``),
-    # summed over a backend's replays: conflict-resolution passes, and
-    # windows cleared by WIS (first sweep plus every re-clear)
+    # summed over a backend's replays: conflict-resolution passes, windows
+    # cleared by WIS (first sweep plus every re-clear), and of those the
+    # windows cleared by a batched DP rather than the per-row scalar DP
     n_passes: int = 0
     n_reclears: int = 0
+    n_reclears_vec: int = 0
 
 
 # ---------------------------------------------------------------------------

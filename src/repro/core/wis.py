@@ -11,7 +11,8 @@ Classical DP after sorting by end time — O(M log M):
 
 Three per-window implementations:
 
-* :func:`wis_select`       — numpy host path (the scheduler's default).
+* :func:`wis_select`       — numpy host path: the plain per-window reference
+                             (used when passed explicitly as ``selector``).
 * :func:`wis_select_jax`   — jit-able JAX path (sort + searchsorted +
                              ``lax.scan`` DP + ``lax.while_loop`` backtrack);
                              mirrored by the Pallas kernel ``kernels/wis_dp``.
@@ -32,7 +33,7 @@ settle (the clearing-side twin of the PR-2 scoring engine):
   in-flight scoring dispatch (scores never round-trip through the host);
   the returned :class:`SettlePrefetch` materializes at settle time.
 * :func:`make_round_selector` maps the ``SchedulerConfig.wis_impl`` knob to
-  a selector (None → the historical per-window :func:`wis_select` loop).
+  a selector (None → the batched host settle, ``RoundSelector("numpy")``).
 
 Banned lanes are excluded by ZEROING their weights rather than re-packing:
 under the strict ``>`` tie rule a zero-weight lane is never taken and its
@@ -61,6 +62,7 @@ __all__ = [
     "SettlePrefetch",
     "PackedSettle",
     "make_round_selector",
+    "HOST_SELECTOR",
     "predispatch_settle",
     "wis_select_batch",
 ]
@@ -253,7 +255,7 @@ class PackedSettle:
     """
 
     __slots__ = ("members", "idx_sorted", "pred", "wmat", "n_windows",
-                 "lanes", "row_len", "_pred_rows")
+                 "lanes", "row_len", "_rows")
 
     def __init__(self, members, idx_sorted, pred, wmat):
         self.members = members
@@ -264,18 +266,26 @@ class PackedSettle:
         self.lanes = idx_sorted.shape[1]
         self.row_len = np.fromiter((len(m) for m in members), np.intp,
                                    count=len(members))
-        # lazily materialized python predecessor lists (per-row scalar DP)
-        self._pred_rows: list = [None] * self.n_windows
+        # lazily materialized per-row python lists (per-row scalar DP)
+        self._rows: list = [None] * self.n_windows
 
-    def pred_row(self, k: int) -> list:
-        row = self._pred_rows[k]
+    def row(self, k: int) -> tuple:
+        """Window k's real lanes as ``(pool indices, weights, preds)``
+        python lists plus the pool indices as an array (for ban gathers),
+        built once per pack: a re-clear of a small window then costs no
+        numpy call that scales with the pool."""
+        row = self._rows[k]
         if row is None:
-            row = self._pred_rows[k] = self.pred[k, : self.row_len[k]].tolist()
+            n = int(self.row_len[k])
+            idx = self.idx_sorted[k, :n]
+            row = self._rows[k] = (idx.tolist(), self.wmat[k, :n].tolist(),
+                                   self.pred[k, :n].tolist(), idx)
         return row
 
     def fill_weights(self, sel_scores: np.ndarray) -> None:
         """Gather the (sorted-lane) weight matrix from per-pool scores."""
         sel_scores = np.asarray(sel_scores, np.float64)
+        self._rows = [None] * self.n_windows
         if sel_scores.size == 0:
             self.wmat = np.zeros(self.idx_sorted.shape, np.float64)
             return
@@ -464,38 +474,48 @@ class RoundSelector:
     #: work, no per-step numpy kernel overhead)
     _VECTOR_MIN_ROWS = 6.0
 
+    def vectorizes(self, packed: PackedSettle, rows) -> bool:
+        """True when :meth:`select_rows` clears these rows in one batched
+        DP (the lane-major host DP, or the device kernel); False when each
+        row runs the scalar DP from the retained buffers."""
+        if self.impl != "numpy":
+            return True
+        return (int(packed.row_len[rows].sum())
+                >= self._VECTOR_MIN_ROWS * packed.lanes)
+
     def select_rows(self, packed: PackedSettle, requests) -> List[List[int]]:
         """Like :meth:`select` but with a per-row banned mask — the form the
         GlobalAssignment lockstep replays use (rows from different candidate
         configurations share the packed buffers but not their bans)."""
         if not requests:
             return []
-        if self.impl == "numpy":
-            total = int(packed.row_len[[k for k, _ in requests]].sum())
-            if total < self._VECTOR_MIN_ROWS * packed.lanes:
-                # small batch (conflict re-clears, narrow rounds): scalar DP
-                # per row from the retained sort/pred — identical selections
-                return [self._select_row_scalar(packed, k, banned)
-                        for k, banned in requests]
         rows = [k for k, _ in requests]
+        if not self.vectorizes(packed, rows):
+            # small batch (conflict re-clears, narrow rounds): scalar DP
+            # per row from the retained sort/pred — identical selections
+            return [self._select_row_scalar(packed, k, banned)
+                    for k, banned in requests]
         idx_rows = packed.idx_sorted[rows]
         w = packed.wmat[rows]  # fancy indexing copies — safe to mutate
-        first_banned = requests[0][1]
-        if all(b is first_banned for _, b in requests):
-            # common case (one shared ban state): one vectorized masking
-            if first_banned is not None and first_banned.any():
-                w[(idx_rows >= 0) & first_banned[np.clip(idx_rows, 0, None)]] = 0.0
-        else:
-            for r, (k, banned) in enumerate(requests):
-                if banned is not None and banned.any():
-                    bi = idx_rows[r]
-                    w[r, (bi >= 0) & banned[np.clip(bi, 0, None)]] = 0.0
+        valid = idx_rows >= 0
+        safe = np.where(valid, idx_rows, 0)
+        # one masking per run of rows sharing a ban state (a replay's dirty
+        # windows are consecutive), over those rows' own lanes only
+        start = 0
+        for r in range(1, len(requests) + 1):
+            if r < len(requests) and requests[r][1] is requests[start][1]:
+                continue
+            banned = requests[start][1]
+            if banned is not None:
+                ws = w[start:r]
+                ws[valid[start:r] & banned[safe[start:r]]] = 0.0
+            start = r
         sel = self._dispatch(w, packed.pred[rows])
-        # single nonzero + row-split instead of W flatnonzero calls
+        # single nonzero + row bounds instead of W flatnonzero calls
         sel_rows, sel_lanes = np.nonzero(sel)
-        pool_idx = idx_rows[sel_rows, sel_lanes]
-        splits = np.searchsorted(sel_rows, np.arange(1, len(requests)))
-        return [part.tolist() for part in np.split(pool_idx, splits)]
+        flat = idx_rows[sel_rows, sel_lanes].tolist()
+        ends = np.cumsum(np.bincount(sel_rows, minlength=len(rows))).tolist()
+        return [flat[a:b] for a, b in zip([0] + ends[:-1], ends)]
 
     @staticmethod
     def _select_row_scalar(packed: PackedSettle, k: int, banned) -> List[int]:
@@ -504,18 +524,14 @@ class RoundSelector:
         Skips the re-sort the per-window host path pays on every re-clear
         (order and predecessors were fixed at pack time); python floats ARE
         IEEE float64, so the arithmetic is bit-identical to ``wis_select``.
+        Bans are gathered for the row's own lanes only.
         """
-        n = int(packed.row_len[k])
+        idx, w, p, ia = packed.row(k)
+        n = len(idx)
         if n == 0:
             return []
-        idx_row = packed.idx_sorted[k]
-        w = packed.wmat[k, :n].tolist()
-        if banned is not None and banned.any():
-            bi = idx_row[:n]
-            bm = (bi >= 0) & banned[np.clip(bi, 0, None)]
-            for j in np.flatnonzero(bm):
-                w[j] = 0.0
-        p = packed.pred_row(k)
+        if banned is not None:
+            w = [0.0 if b else x for b, x in zip(banned[ia].tolist(), w)]
         dp = [0.0] * (n + 1)
         take = [False] * n
         for j in range(n):
@@ -529,12 +545,12 @@ class RoundSelector:
         j = n
         while j > 0:
             if take[j - 1]:
-                sel.append(j - 1)
+                sel.append(idx[j - 1])
                 j = p[j - 1]
             else:
                 j -= 1
         sel.reverse()
-        return [int(idx_row[s]) for s in sel]
+        return sel
 
     def _dispatch(self, w: np.ndarray, pred: np.ndarray) -> np.ndarray:
         impl = self._effective_impl()
@@ -650,17 +666,22 @@ def predispatch_settle(selector, backend, n_windows: int, win_idx, view,
 def make_round_selector(impl: Optional[str], mesh=None, health=None):
     """Map the ``wis_impl`` knob (plus an optional auction mesh) to a selector.
 
-    None → the historical per-window :func:`wis_select` host loop (the
-    default: byte-identical, no device involvement); "numpy" → the batched
-    float64 host backend (byte-identical by construction, one python DP
-    loop per LANE instead of per candidate per window); "ref" / "pallas" →
-    the device backends in ``kernels/wis_dp`` (float32 DP, fused score→
-    clear dispatch).  ``mesh`` shards the device backends' window rows
+    None or "numpy" → the batched host settle, the float64 host backend
+    (byte-identical to the per-window :func:`wis_select` loop, which stays
+    the plain reference a caller passes explicitly as ``selector``: the
+    round is sorted and packed once, re-clears zero banned weights and run
+    from the retained pack); "ref" / "pallas" → the device backends in
+    ``kernels/wis_dp`` (float32 DP, fused score→clear dispatch).  ``mesh``
+    shards the device backends' window rows
     (``launch.mesh.make_auction_mesh``); host paths ignore it.
     """
-    if impl is None:
-        return wis_select
-    return RoundSelector(impl, mesh=mesh, health=health)
+    return RoundSelector(impl or "numpy", mesh=mesh, health=health)
+
+
+#: the default ``selector`` of every settle entry point (the policies'
+#: ``settle``, ``fixed_point_settle``, ``clear_round``, ``settle_round``):
+#: the batched host settle, shared (a host RoundSelector holds no state)
+HOST_SELECTOR = make_round_selector(None)
 
 
 def wis_select_batch(starts, ends, weights, valid=None, *, impl: str = "numpy"):

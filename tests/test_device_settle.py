@@ -8,8 +8,10 @@ Pins the tentpole's correctness contract:
   tests use float32-exact interval/weight grids so the float32 device DP
   and the float64 host DP make bit-identical decisions);
 * ``fixed_point_settle`` under every ``RoundSelector`` backend (host-batched
-  "numpy", device "ref"/"pallas") is byte-identical to the per-window host
-  loop, with and without work budgets, serial and pipelined;
+  "numpy", which is also the default, device "ref"/"pallas") is
+  byte-identical to the per-window host loop (``wis_select``, passed
+  explicitly as the independent reference), with and without work budgets,
+  serial and pipelined;
 * the fused score→clear dispatch (``wis_impl`` device backends consuming
   in-flight device scores) matches the host path, and the batched dispatch
   never retraces after its per-bucket warmup;
@@ -230,11 +232,14 @@ def _slices():
             SliceSpec("s5", 5 * GB, n_chips=1)]
 
 
-def _run_sched(wis_impl, *, pipeline=True, clearing=None, score_impl=None):
+def _run_sched(wis_impl, *, pipeline=True, clearing=None, score_impl=None,
+               selector=None):
     pol = Policy() if clearing is None else Policy(name="x", clearing=clearing)
     cfg = SchedulerConfig.from_policy(pol, wis_impl=wis_impl,
                                       score_impl=score_impl)
     sched = JasdaScheduler(_slices(), cfg)
+    if selector is not None:
+        sched._wis_selector = selector
     simulate(sched, make_workload(40, seed=3, arrival_rate=0.3),
              SimConfig(t_end=900.0, seed=2, pipeline=pipeline))
     return (
@@ -244,34 +249,42 @@ def _run_sched(wis_impl, *, pipeline=True, clearing=None, score_impl=None):
     )
 
 
-@pytest.mark.parametrize("impl", ALL_IMPLS)
+def _run_reference(**kw):
+    """The scheduler on the plain per-window ``wis_select`` loop: the
+    reference every settle backend, the default included, is held to."""
+    return _run_sched(None, selector=wis_select, **kw)
+
+
+@pytest.mark.parametrize("impl", [None, *ALL_IMPLS])
 def test_scheduler_byte_identical_under_wis_impl(impl):
-    assert _run_sched(impl) == _run_sched(None)
+    assert _run_sched(impl) == _run_reference()
 
 
 @pytest.mark.parametrize("impl", [None, "numpy", "ref"])
 def test_pipelined_equals_serial_under_device_selector(impl):
-    assert (_run_sched(impl, pipeline=True)
-            == _run_sched(impl, pipeline=False))
+    serial = _run_sched(impl, pipeline=False)
+    assert _run_sched(impl, pipeline=True) == serial
+    assert serial == _run_reference(pipeline=False)
 
 
 @pytest.mark.parametrize("clearing", [GlobalAssignment(), FairShare()])
 def test_backends_identical_under_batched_selector(clearing):
-    base = _run_sched(None, clearing=clearing)
+    base = _run_reference(clearing=clearing)
     assert _run_sched("numpy", clearing=clearing) == base
+    assert _run_sched(None, clearing=clearing) == base
 
 
 def test_scheduler_fused_path_byte_identical():
     """Forced device scoring keeps the handle in flight, so the scheduler's
     predispatch (fused score→clear) actually runs — and must not change a
     single commit."""
-    base = _run_sched(None, score_impl="ref")
+    base = _run_reference(score_impl="ref")
     assert _run_sched("ref", score_impl="ref") == base
     assert _run_sched("ref", score_impl="ref", pipeline=False) == base
 
 
-def test_global_assignment_lockstep_equals_serial():
-    """Conflict-heavy pool: lockstep config-batch replays == host replays."""
+def _conflict_heavy_round():
+    """5 windows, 8 jobs each bidding the same interval on two slices."""
     rng = np.random.default_rng(13)
     n_windows = 5
     windows = [Window(f"s{k}", (4 + 2 * k) * GB, 0.0, 100.0)
@@ -288,8 +301,15 @@ def test_global_assignment_lockstep_equals_serial():
             pool.append(_variant(f"J{j}", f"s{k}", t0, dur,
                                  float(rng.uniform(0.1, 0.9)),
                                  vid=f"J{j}/s{k}/v{len(pool)}"))
+    return windows, pool
+
+
+def test_global_assignment_lockstep_equals_serial():
+    """Conflict-heavy pool: lockstep config-batch replays == host replays."""
+    windows, pool = _conflict_heavy_round()
     ga = GlobalAssignment()
-    base = clear_round(windows, pool, ScoringPolicy(), clearing=ga)
+    base = clear_round(windows, pool, ScoringPolicy(), clearing=ga,
+                       selector=wis_select)
     assert base.n_conflicts > 0  # the scenario must actually exercise replays
     for impl in ALL_IMPLS:
         rr = clear_round(windows, pool, ScoringPolicy(), clearing=ga,
@@ -298,6 +318,66 @@ def test_global_assignment_lockstep_equals_serial():
                 == [tuple(v.variant_id for v in r.selected)
                     for r in base.results]), impl
         assert abs(rr.total_score - base.total_score) < 1e-9
+
+
+@pytest.mark.parametrize("clearing", [GreedyWIS(), GlobalAssignment(),
+                                      FairShare()],
+                         ids=["greedy", "global", "fairshare"])
+def test_default_settle_equals_per_window_reference(clearing):
+    """The default settle (the batched host selector) clears a
+    conflict-heavy round exactly as the per-window ``wis_select`` loop:
+    same selections in the same order, same fixed-point work."""
+    windows, pool = _conflict_heavy_round()
+    fit, win_idx, view = assign_bids(windows, pool)
+    scores = np.random.default_rng(5).uniform(0.05, 1.0, len(fit))
+    ages = {f"J{j}": j / 8 for j in range(8)}
+    kw = dict(view=view, ages=ages)
+    ref = clearing.settle(windows, fit, win_idx, scores, selector=wis_select,
+                          **kw)
+    got = clearing.settle(windows, fit, win_idx, scores, **kw)
+    assert ref.n_conflicts > 0
+    assert got.selected_idx == ref.selected_idx
+    assert got.total_score == ref.total_score
+    assert (got.n_passes, got.n_reclears) == (ref.n_passes, ref.n_reclears)
+    # the per-window loop runs no batched DP; the default's batched share
+    # is a part of its re-clears
+    assert ref.n_reclears_vec == 0
+    assert 0 <= got.n_reclears_vec <= got.n_reclears
+    # the scheduler, clear_round and the pipeline all build it from None
+    sel = make_round_selector(None)
+    assert isinstance(sel, RoundSelector) and sel.impl == "numpy"
+    sched = JasdaScheduler(_slices(), Policy(name="x", clearing=clearing))
+    assert isinstance(sched._wis_selector, RoundSelector)
+    assert sched.settle_backend == "numpy"
+
+
+def test_small_row_reclears_take_the_scalar_branch(monkeypatch):
+    """Eight windows of six candidates: the ban-free sweep carries enough
+    lanes for the lane-major DP; the one conflict dirties a single window,
+    whose re-clear runs the scalar DP from the retained pack."""
+    windows = [Window(f"s{k}", 8 * GB, 0.0, 100.0) for k in range(8)]
+    pool = [_variant(f"J{k}{i}", f"s{k}", 10.0 * i, 10.0, 0.5,
+                     vid=f"s{k}/{i}")
+            for k in range(8) for i in range(6)]
+    # X bids [0, 10) on s0 and s1, beating both incumbents: it keeps s0
+    pool += [_variant("X", "s0", 0.0, 10.0, 0.9, vid="x0"),
+             _variant("X", "s1", 0.0, 10.0, 0.8, vid="x1")]
+    fit, win_idx, view = assign_bids(windows, pool)
+    scores = np.array([v.local_utility for v in fit])
+    calls = []
+    orig = RoundSelector._select_row_scalar
+
+    def spy(packed, k, banned):
+        calls.append(k)
+        return orig(packed, k, banned)
+
+    monkeypatch.setattr(RoundSelector, "_select_row_scalar",
+                        staticmethod(spy))
+    rr = GreedyWIS().settle(windows, fit, win_idx, scores, view=view)
+    assert rr.n_conflicts == 1
+    assert [v.variant_id for v in rr.results[1].selected][0] == "s1/0"
+    assert calls == [1]
+    assert (rr.n_reclears, rr.n_reclears_vec) == (9, 8)
 
 
 # ---------------------------------------------------------------------------

@@ -21,7 +21,7 @@ from repro.core.policy import GlobalAssignment, GreedyWIS, Policy
 from repro.core.scheduler import SchedulerConfig
 from repro.core.trp import fmp_standard
 from repro.core.types import Variant, Window
-from repro.core.wis import make_round_selector
+from repro.core.wis import make_round_selector, wis_select
 
 GB = 1 << 30
 
@@ -50,6 +50,7 @@ def _one_conflict_round():
 # window (2 combos), each seeded with the first sweep: a pass that bans,
 # one re-clear, a pass that finds nothing -> 2 + 2 + 2 passes, 3 + 1 + 1
 # windows.  A batched selector runs GlobalAssignment's replays in lockstep.
+# The per-window loop is the plain ``wis_select`` passed explicitly.
 @pytest.mark.parametrize("clearing,impl,passes,reclears", [
     (GreedyWIS(), None, 2, 3),
     (GreedyWIS(), "numpy", 2, 3),
@@ -58,12 +59,14 @@ def _one_conflict_round():
 ], ids=["greedy", "greedy-batched", "global-serial", "global-lockstep"])
 def test_settle_counts_fixed_point_work(clearing, impl, passes, reclears):
     windows, fit, win_idx, scores = _one_conflict_round()
-    rr = clearing.settle(windows, fit, win_idx, scores,
-                         selector=make_round_selector(impl))
+    selector = wis_select if impl is None else make_round_selector(impl)
+    rr = clearing.settle(windows, fit, win_idx, scores, selector=selector)
     assert rr.n_conflicts == 1
     assert [[v.variant_id for v in r.selected] for r in rr.results] == \
         [["a"], ["c"]]
     assert (rr.n_passes, rr.n_reclears) == (passes, reclears)
+    # two-lane windows: every batched-selector clear is a scalar row
+    assert rr.n_reclears_vec == 0
 
 
 def _sched(clearing=GreedyWIS(), n_jobs=18):
@@ -89,6 +92,7 @@ def test_audit_row_carries_the_settle_counts(clearing):
             assert (row.n_passes, row.n_reclears) == (0, 0)
             continue
         assert (row.n_passes, row.n_reclears) == (rr.n_passes, rr.n_reclears)
+        assert row.n_reclears_vec == rr.n_reclears_vec <= row.n_reclears
         assert row.n_passes >= 1 and row.n_reclears >= row.n_windows
     assert sum(r.n_conflicts for r in sched.log) > 0
 
