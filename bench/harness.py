@@ -2,7 +2,16 @@
 
 Everything a cell needs is found by name from ``BENCHMARK.json``: its
 configuration file (``bench/configs/``), its traffic mix
-(``bench/traffic/``) and one reader per metric (``bench/metrics/``).
+(``bench/traffic/``), one reader per metric (``bench/metrics/``) and one
+module per extra check the configuration lists (``bench/checks/``).
+
+A cell declares its deployment in those data files, and the harness
+resolves each declaration by the names the program publishes: the
+configuration's optional ``"service"`` block names a repartition policy
+(a subclass of ``RepartitionPolicy``, by its ``name``), its
+``repartition_dt`` and the ``MigrationConfig`` fields; the mix's
+``"admission"`` names an ``AdmissionPolicy`` subclass the same way.  What
+a cell does not declare is built as the service's defaults.
 
 The system under test is driven only through its public surface:
 ``JasdaScheduler(slices, policy)``, ``JasdaService(scheduler, arrivals,
@@ -99,13 +108,31 @@ def resolve_cell(name: str, repo: Path = REPO) -> Cell:
         per_layer=[m for m in bench["per_layer"] if _listed_for(m, name)])
 
 
-def metric_reader(name: str):
-    """The ``read(ctx)`` function of ``bench/metrics/<name>.py``."""
-    path = BENCH / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
+#: where ``check(run)`` of each extra check a configuration lists lives
+CHECKS = BENCH / "checks"
+
+
+def _module_attr(path: Path, attr: str):
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{path.parent.name}_{path.stem}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return getattr(mod, attr)
+
+
+def metric_reader(name: str):
+    """The ``read(ctx)`` function of ``bench/metrics/<name>.py``."""
+    return _module_attr(BENCH / "metrics" / f"{name}.py", "read")
+
+
+def check_reader(name: str):
+    """The ``check(run)`` function of ``bench/checks/<name>.py``: it returns
+    ``{number: {"value": v, "limit": l}}``, correct where ``v <= l``."""
+    path = CHECKS / f"{name}.py"
+    if not path.is_file():
+        have = sorted(p.stem for p in CHECKS.glob("*.py"))
+        raise ValueError(f"no check {name!r} in {CHECKS} (have {have})")
+    return _module_attr(path, "check")
 
 
 # ---------------------------------------------------------------------------
@@ -135,13 +162,57 @@ def build_policy(config: dict):
     return presets[config["policy"]]()
 
 
-def build_admission(mix: dict):
-    from repro.service import AcceptAll
+def named_subclass(base: type, name: str) -> type:
+    """The subclass of ``base`` whose own ``name`` attribute is ``name``,
+    among those the program has defined; an unknown name raises, naming
+    what exists."""
+    found: Dict[str, type] = {}
+    todo = list(base.__subclasses__())
+    while todo:
+        cls = todo.pop()
+        todo.extend(cls.__subclasses__())
+        own = cls.__dict__.get("name")
+        if isinstance(own, str) and found.setdefault(own, cls) is not cls:
+            raise ValueError(f"two {base.__name__} subclasses are named "
+                             f"{own!r}: {found[own].__name__}, {cls.__name__}")
+    if name not in found:
+        raise ValueError(f"unknown {base.__name__} {name!r} "
+                         f"(have {sorted(found)})")
+    return found[name]
 
-    kind = mix["admission"]["policy"]
-    if kind != "accept_all":
-        raise ValueError(f"unknown admission policy {kind!r}")
-    return AcceptAll()
+
+def build_admission(mix: dict):
+    """The mix's ``{"policy": <name>, **params}`` as an AdmissionPolicy."""
+    from repro.service.admission import AdmissionPolicy
+
+    params = dict(mix["admission"])
+    return named_subclass(AdmissionPolicy, params.pop("policy"))(**params)
+
+
+def service_components(config: dict) -> dict:
+    """The ``ServiceConfig`` fields the configuration's ``"service"`` block
+    declares: ``repartition`` ({"policy": <name>, "params": {...}}),
+    ``repartition_dt`` and ``migration`` (the ``MigrationConfig`` fields).
+    No block, or a key left out, keeps the service's default (None)."""
+    from repro.core.repartition import MigrationConfig, RepartitionPolicy
+
+    block = dict(config.get("service", {}))
+    out = {}
+    if "repartition" in block:
+        decl = dict(block.pop("repartition"))
+        cls = named_subclass(RepartitionPolicy, decl.pop("policy"))
+        out["repartition"] = cls(**decl.pop("params", {}))
+        if decl:
+            raise ValueError(f"unknown keys {sorted(decl)} in "
+                             "service.repartition (have policy, params)")
+    if "repartition_dt" in block:
+        out["repartition_dt"] = float(block.pop("repartition_dt"))
+    if "migration" in block:
+        out["migration"] = MigrationConfig(**block.pop("migration"))
+    if block:
+        raise ValueError(f"unknown keys {sorted(block)} in the service block "
+                         "(have repartition, repartition_dt, migration)")
+    return out
 
 
 def seeds(seed: int) -> Dict[str, np.random.SeedSequence]:
@@ -345,6 +416,9 @@ class Run:
     round_walls: List[float] = field(default_factory=list)
     #: Python garbage collections in the window: {generation: [count, s]}
     gc_pauses: Dict[int, list] = field(default_factory=dict)
+    #: the system under test, for extra checks that read its logs
+    scheduler: Optional[object] = None
+    service: Optional[object] = None
 
     def latencies(self) -> List[float]:
         """Admit → first award wall seconds of every job admitted in the
@@ -382,7 +456,8 @@ def build_system(cell: Cell, streams: dict, on_pull):
     svc = JasdaService(
         sched, stream,
         config=ServiceConfig(round_dt=float(mix["round_dt"]), t_end=HORIZON,
-                             seed=int(streams["exec"].generate_state(1)[0])),
+                             seed=int(streams["exec"].generate_state(1)[0]),
+                             **service_components(cell.config)),
         admission=build_admission(mix))
     return sched, svc, stream
 
@@ -450,6 +525,7 @@ def drive(cell: Cell, seed: int, seconds: float, *, trace: bool,
     run.setup_marks["kernels_warm"] = time.perf_counter() - t_proc0
     sched, svc, stream = build_system(cell, streams, on_pull)
     run.jobs = stream.jobs
+    run.scheduler, run.service = sched, svc
     clock = JobClock(svc.metrics)
     run.clock = clock
 
@@ -539,11 +615,20 @@ def drive(cell: Cell, seed: int, seconds: float, *, trace: bool,
 
 def check_outputs(run: Run, control: bool = False) -> dict:
     """Compare the sampled rounds with the plain reference (bench/reference);
-    with ``control`` the reference in bfloat16 stands in for the program."""
+    with ``control`` the reference in bfloat16 stands in for the program's
+    scores.  The numbers of each extra check the configuration lists
+    (``"checks"``) join them, control or not: they read the run itself."""
     jobs = {j: reference.JobMemory.from_draw(d.steady_bytes, run.cell.mix)
             for j, d in run.jobs.items()}
-    return reference.check_rounds(run.sampled, run.cell.config, jobs,
-                                  control=control)
+    out = reference.check_rounds(run.sampled, run.cell.config, jobs,
+                                 control=control)
+    for name in run.cell.config.get("checks", ()):
+        for key, c in check_reader(name)(run).items():
+            if key in out:
+                raise ValueError(f"check {name!r} reports {key!r}, which "
+                                 "another check already reports")
+            out[key] = {"value": c["value"], "limit": c["limit"]}
+    return out
 
 
 # ---------------------------------------------------------------------------

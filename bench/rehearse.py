@@ -6,10 +6,10 @@
 
 ``--rounds`` runs the cell's system for N rounds from its set-up and prints,
 for each block of ``--block`` rounds, the mean live jobs, bids, windows,
-awards and conflicts a round, and the jobs completed: the levelness check
-of a traffic mix.  Decisions do not depend on the device, so the counts
-carry over to the chip; the times do not.  ``--rate`` overrides the
-mix's arrival rate for a sweep.
+awards and conflicts a round, the jobs completed, and the jobs arrived and
+shed so far: the levelness check of a traffic mix.  Decisions do not depend
+on the device, so the counts carry over to the chip; the times do not.
+``--rate`` overrides a Poisson mix's arrival rate for a sweep.
 
 ``--compile`` compiles the ``jasda_score`` kernel for a described v5e (no
 chip) at the largest score bucket that ``--rounds`` saw (``--bucket``
@@ -51,6 +51,7 @@ def levelness(cell, seed: int, rounds: int, block: int) -> dict:
                      "awards": len(rr.selected) if rr else 0,
                      "conflicts": rr.n_conflicts if rr else 0,
                      "completed": svc.metrics.n_completed - last["completed"],
+                     "arrived": svc.metrics.n_arrived,
                      "shed": svc.metrics.n_shed})
         last["t"], last["completed"] = now, svc.metrics.n_completed
         return rr
@@ -67,11 +68,14 @@ def levelness(cell, seed: int, rounds: int, block: int) -> dict:
                 for k in ("wall", "live", "bids", "windows", "awards",
                           "conflicts", "completed")}
         mean.update(first=part[0]["t"], max_bids=max(r["bids"] for r in part),
+                    min_live=min(r["live"] for r in part),
+                    max_live=max(r["live"] for r in part),
                     device_rounds=sum(r["bids"] >= 256 for r in part),
                     min_bids=min(r["bids"] for r in part),
-                    shed=part[-1]["shed"])
+                    arrived=part[-1]["arrived"], shed=part[-1]["shed"])
         blocks.append(mean)
     return {"blocks": blocks,
+            "shed_share": svc.metrics.n_shed / max(1, svc.metrics.n_arrived),
             "max_bids": max((r["bids"] for r in rows), default=0),
             "device_share": (sum(r["bids"] >= 256 for r in rows)
                              / max(1, len(rows))),
@@ -124,7 +128,7 @@ def main(argv=None) -> int:
     cell = harness.resolve_cell(args.workload)
     if args.rate is not None:
         cell.mix["arrivals"]["rate"] = args.rate
-    out = {"workload": args.workload, "rate": cell.mix["arrivals"]["rate"]}
+    out = {"workload": args.workload, "arrivals": cell.mix["arrivals"]}
     bucket = args.bucket
     if args.rounds:
         lv = levelness(cell, args.seed, args.rounds, args.block)
@@ -132,6 +136,7 @@ def main(argv=None) -> int:
             print(json.dumps({k: round(v, 4) if isinstance(v, float) else v
                               for k, v in b.items()}), flush=True)
         out.update(max_bids=lv["max_bids"], device_share=lv["device_share"],
+                   shed_share=lv["shed_share"],
                    wall_per_round=lv["wall_per_round"])
         if bucket is None:
             bucket = 256

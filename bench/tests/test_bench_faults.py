@@ -15,7 +15,7 @@ from repro.core.policy import GlobalAssignment, GreedyWIS
 from repro.core.scoring import ScoreHandle
 from repro.core.types import RoundResult
 
-CELLS = ["pod256-steady", "migdgx-steady"]
+CELLS = ["pod256-steady", "migdgx-steady", "pod256-steady+burst"]
 
 
 def _measure(name):

@@ -44,6 +44,9 @@ def test_cell_resolves(cell):
     resolved = harness.resolve_cell(cell["name"])
     traffic.load_mix(traffic.mix_path(harness.BENCH, cell["traffic"]))
     harness.build_admission(resolved.mix)
+    harness.build_system(resolved, harness.seeds(1), None)
+    for name in resolved.config.get("checks", ()):
+        assert callable(harness.check_reader(name))
     names = {m["name"] for m in resolved.end_to_end}
     assert "setup_s" in names and len(names) >= 2
     assert resolved.per_layer

@@ -116,7 +116,8 @@ NEW_METRICS = ("announce_ms", "score_dispatch_ms", "score_wait_ms",
                "spec_used_share")
 
 
-@pytest.mark.parametrize("name", ["pod256-steady", "migdgx-steady"])
+@pytest.mark.parametrize("name", ["pod256-steady", "migdgx-steady",
+                                  "pod256-steady+burst"])
 def test_traced_run_reports_the_program_metrics(name):
     cell = tiny_cell(name)
     res = harness.measure(cell, 3141592653589, 1.0, True,

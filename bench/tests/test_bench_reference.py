@@ -51,7 +51,8 @@ def test_memory_profile_grid_and_safety():
     assert job.p_exceed(8.5 * gib) > 0.5
 
 
-@pytest.mark.parametrize("name", ["migdgx-steady", "pod256-steady"])
+@pytest.mark.parametrize("name", ["migdgx-steady", "pod256-steady",
+                                  "pod256-steady+burst"])
 def test_reference_agrees_and_control_fails(name):
     cell = tiny_cell(name)
     run = harness.drive(cell, 20240917, 1.0, trace=False,

@@ -28,7 +28,8 @@ def test_settle_vector_share_reads_the_batched_share():
     assert read(_ctx([SimpleNamespace(n_reclears=0, n_reclears_vec=0)])) is None
 
 
-@pytest.mark.parametrize("name", ["pod256-steady", "migdgx-steady"])
+@pytest.mark.parametrize("name", ["pod256-steady", "migdgx-steady",
+                                  "pod256-steady+burst"])
 def test_traced_run_reports_the_settle_vector_share(name):
     cell = tiny_cell(name)
     # a trace directory of its own, apart from other test files' traced

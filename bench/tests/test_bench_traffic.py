@@ -7,6 +7,7 @@ import pytest
 
 import harness
 import traffic
+from bench_cells import burst_cell
 
 BLOCK = 16
 
@@ -69,3 +70,85 @@ def test_arrivals_come_at_the_mix_rate():
     mix = _mix(rate=4.0)
     stream, _ = _pull(mix, 5, 500.0)
     assert len(stream.jobs) / 500.0 == pytest.approx(4.0, rel=0.1)
+
+
+def _burst_mix():
+    return burst_cell("pod256-steady").mix
+
+
+def _arrival_times(mix, seed, t_end):
+    """The point process alone: arrival times up to ``t_end`` (no jobs), and
+    every switch time it drew, in order (quiet -> burst first).  Each switch
+    lies one dwell after the last, so the switches are the running sum of
+    the first switch and the dwells drawn after it."""
+    stream = traffic.JobStream(mix, np.random.default_rng(seed))
+    proc = stream.arrivals
+    switches, dwell = [proc.switch_t], proc._dwell
+
+    def keep():
+        d = dwell()
+        switches.append(switches[-1] + d)
+        return d
+
+    proc._dwell = keep
+    times, t = [], 0.0
+    while True:
+        t = stream._next_arrival(t)
+        if t > t_end:
+            assert switches[-1] == proc.switch_t
+            return switches, times
+        times.append(t)
+
+
+def test_bursts_come_at_the_same_times_on_every_seed():
+    a, times_a = _arrival_times(_burst_mix(), 1, 2000.0)
+    b, times_b = _arrival_times(_burst_mix(), 3141592653589793, 2000.0)
+    n = min(len(a), len(b))
+    assert n > 20 and a[:n] == b[:n]
+    assert times_a != times_b
+
+
+def test_burst_rate_and_share_of_time_in_bursts():
+    mix = _burst_mix()
+    arr = mix["arrivals"]
+    t_end = 40000.0
+    switches, times = _arrival_times(mix, 7, t_end)
+    dq, db = arr["mean_dwell_quiet"], arr["mean_dwell_burst"]
+    mean_rate = (arr["rate_quiet"] * dq + arr["rate_burst"] * db) / (dq + db)
+    assert len(times) / t_end == pytest.approx(mean_rate, rel=0.05)
+    # switches alternate quiet -> burst -> quiet ...; time in bursts is
+    # every second interval, cut at t_end
+    edges = [0.0] + [min(s, t_end) for s in switches]
+    in_burst = sum(edges[i + 1] - edges[i]
+                   for i in range(1, len(edges) - 1, 2))
+    assert in_burst / t_end == pytest.approx(db / (dq + db), rel=0.10)
+
+
+def test_arrivals_within_a_burst_come_at_the_burst_rate():
+    mix = _burst_mix()
+    switches, times = _arrival_times(mix, 11, 20000.0)
+    sw = np.asarray(switches)
+    # a time is in a burst when an odd number of switches lie before it
+    burst = np.searchsorted(sw, np.asarray(times)) % 2 == 1
+    edges = np.concatenate([[0.0], np.minimum(sw, 20000.0)])
+    t_burst = float(np.sum(np.diff(edges)[1::2]))
+    assert burst.sum() / t_burst == pytest.approx(
+        mix["arrivals"]["rate_burst"], rel=0.05)
+
+
+def test_an_arrival_key_the_process_lacks_raises():
+    mix = _burst_mix()
+    mix["arrivals"]["rate"] = 6.6
+    with pytest.raises(TypeError, match="rate"):
+        traffic.JobStream(mix, np.random.default_rng(1))
+
+
+def test_burst_dwells_are_the_mix_seeds_third_stream():
+    """The gaps and traits are the mix's first two child streams, whatever
+    the process; the burst process spawns the third for its dwells."""
+    mix = _burst_mix()
+    stream = traffic.JobStream(mix, np.random.default_rng(5))
+    third = np.random.SeedSequence(mix["stream"]["base_seed"]).spawn(3)[2]
+    first = np.random.default_rng(third).standard_exponential()
+    assert stream.arrivals.switch_t == \
+        mix["arrivals"]["mean_dwell_quiet"] * first

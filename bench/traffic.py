@@ -1,12 +1,16 @@
 """The one traffic generator: a seeded job stream read from a mix's file.
 
 A traffic mix is a JSON file under ``bench/traffic/``; this module is the
-only code that reads one.  The stream is a copy of the program's Poisson
-point process and job synthesis (``repro.service.arrivals``), kept here so
+only code that reads one.  The stream is a copy of the program's arrival
+processes and job synthesis (``repro.service.arrivals``), kept here so
 that a change to the program cannot change the benchmark's inputs:
 
-* arrivals are a Poisson process at ``arrivals.rate`` jobs per unit of
-  simulated time (each gap a unit exponential over the rate);
+* ``arrivals.process`` names the point process (:data:`PROCESSES`):
+  ``poisson`` at ``rate`` jobs per unit of simulated time (each gap a unit
+  exponential over the rate), or ``burst``, the program's two-state
+  Markov-modulated Poisson process (``BurstArrivals``): ``rate_quiet`` and
+  ``rate_burst``, exponential dwells of mean ``mean_dwell_quiet`` and
+  ``mean_dwell_burst``, starting quiet;
 * each job draws log-uniform work, uniform steady memory, the
   warm-up/steady/burst memory profile of ``fmp_standard`` and, with
   probability ``qos_fraction``, a deadline at work × U(deadline_slack)
@@ -17,6 +21,9 @@ drawn from ``stream.base_seed``, in blocks of ``stream.block``, and the
 run's seed permutes each block.  So every stretch of whole blocks offers
 the same work whatever the seed, and runs of different seeds differ in
 the order of arrivals and the scheduler's decisions, not in the load.
+The burst process's dwell times come from ``stream.base_seed`` too, on a
+stream of their own that no seed permutes: every seed sees the same
+bursts at the same simulated times.
 
 The run's generator is ``numpy.random.default_rng`` seeded from the run's
 seed alone, so the same seed gives the same jobs at the same simulated
@@ -77,6 +84,60 @@ class Shuffled:
         return self._left.pop()
 
 
+class PoissonProcess:
+    """Arrivals at a fixed ``rate``: each gap a unit exponential over it.
+    It draws nothing but the gaps, so it spawns nothing from ``seeds``."""
+
+    def __init__(self, gaps: Shuffled, seeds: np.random.SeedSequence, *,
+                 rate: float):
+        if rate <= 0:
+            raise ValueError("arrivals.rate must be > 0")
+        self.gaps, self.rate = gaps, float(rate)
+
+    def next(self, prev: float) -> float:
+        return prev + self.gaps.next() / self.rate
+
+
+class BurstProcess:
+    """The program's two-state MMPP (``BurstArrivals._next_arrival``): the
+    chain starts quiet; a draw that crosses the switch point is dropped and
+    drawn again from the switch under the new state's rate.  The unit gaps
+    are the stream's shuffled ones; the dwells come from a stream of their
+    own, spawned from the mix's ``seeds``, which no run's seed touches."""
+
+    def __init__(self, gaps: Shuffled, seeds: np.random.SeedSequence, *,
+                 rate_quiet: float, rate_burst: float,
+                 mean_dwell_quiet: float, mean_dwell_burst: float):
+        if min(rate_quiet, rate_burst) <= 0:
+            raise ValueError("arrivals.rate_quiet and rate_burst must be > 0")
+        if min(mean_dwell_quiet, mean_dwell_burst) <= 0:
+            raise ValueError("arrivals.mean_dwell_* must be > 0")
+        self.gaps = gaps
+        self.dwells = np.random.default_rng(seeds.spawn(1)[0])
+        self.rate = {False: float(rate_quiet), True: float(rate_burst)}
+        self.mean_dwell = {False: float(mean_dwell_quiet),
+                           True: float(mean_dwell_burst)}
+        self.burst = False
+        self.switch_t = self._dwell()
+
+    def _dwell(self) -> float:
+        return self.mean_dwell[self.burst] * self.dwells.standard_exponential()
+
+    def next(self, prev: float) -> float:
+        t = prev
+        while True:
+            candidate = t + self.gaps.next() / self.rate[self.burst]
+            if candidate <= self.switch_t:
+                return candidate
+            t = self.switch_t
+            self.burst = not self.burst
+            self.switch_t = t + self._dwell()
+
+
+#: arrival processes by the name a mix gives in ``arrivals.process``
+PROCESSES = {"poisson": PoissonProcess, "burst": BurstProcess}
+
+
 class JobStream:
     """Seeded lazy stream of the program's arrival events (see module doc)."""
 
@@ -92,20 +153,24 @@ class JobStream:
         self._DeadlineExpired = DeadlineExpired
         self.mix = mix
         self.on_pull = on_pull
-        arr = mix["arrivals"]
-        if arr.get("process", "poisson") != "poisson":
-            raise ValueError(f"unknown arrival process {arr.get('process')!r}")
-        self.rate = float(arr["rate"])
-        if self.rate <= 0:
-            raise ValueError("arrivals.rate must be > 0")
+        params = dict(mix["arrivals"])
+        kind = params.pop("process", "poisson")
+        if kind not in PROCESSES:
+            raise ValueError(f"unknown arrival process {kind!r} "
+                             f"(have {sorted(PROCESSES)})")
         self.round_dt = float(mix["round_dt"])
         order = mix["stream"]
         base_seed, block = int(order["base_seed"]), int(order["block"])
-        base = np.random.SeedSequence(base_seed).spawn(2)
-        self._gaps = Shuffled(lambda g: float(g.standard_exponential()),
-                              np.random.default_rng(base[0]), rng, block)
+        # the gaps and traits are the mix's first two child streams; a
+        # process that draws more (the burst dwells) spawns the next ones,
+        # so a mix's gaps and traits do not depend on its process
+        seeds = np.random.SeedSequence(base_seed)
+        base = seeds.spawn(2)
+        gaps = Shuffled(lambda g: float(g.standard_exponential()),
+                        np.random.default_rng(base[0]), rng, block)
         self._traits = Shuffled(self._draw_traits,
                                 np.random.default_rng(base[1]), rng, block)
+        self.arrivals = PROCESSES[kind](gaps, seeds, **params)
         self.jobs: Dict[str, JobDraw] = {}
         self._n = 0
         self._seq = 0
@@ -115,7 +180,7 @@ class JobStream:
 
     # -- the point process -------------------------------------------------
     def _next_arrival(self, prev: float) -> float:
-        return prev + self._gaps.next() / self.rate
+        return self.arrivals.next(prev)
 
     # -- job synthesis (the program's generator family) --------------------
     def _draw_traits(self, g: np.random.Generator):
