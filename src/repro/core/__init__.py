@@ -104,6 +104,8 @@ from .repartition import (  # noqa: F401
     EnergyAware,
     EnergyModel,
     FragmentationAware,
+    Layout,
+    MigLayout,
     MigrationConfig,
     MigrationPlanner,
     Move,

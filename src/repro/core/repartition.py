@@ -18,11 +18,18 @@ through the machinery that already exists:
   halves of one parent interval), split only within the lattice — and
   split/merge products get canonical interval-derived ids
   (``p<offset>c<n>``) that stay bounded under repeated cycles;
+* a **MIG layout** (:class:`MigLayout`) for the A100-80GB placement table
+  (NVIDIA MIG User Guide, ``nvidia-smi mig -lgipp``): profiles 1g.10gb,
+  1g.20gb, 2g.20gb, 3g.40gb, 4g.40gb and 7g.80gb at their legal start
+  slots, where a move reshapes a set of instances on one GPU into another
+  legal set; both layouts speak one protocol (:class:`Layout`), and a
+  policy's ``layout`` field picks the coordinator's;
 * a **policy protocol** (:class:`RepartitionPolicy`) with three backends:
   :class:`StaticInventory` (default; proposes nothing, byte-identical to
   a run without the subsystem), :class:`FragmentationAware` (split/merge
   driven by :func:`fragmentation_index` over announced window capacities
-  vs. the pending pool's ``min_capacity`` demand histogram, which also
+  vs. the pending pool's demand floors (``min_capacity``, raised to the
+  smallest profile the job is θ-safe on), which also
   feeds the ``frag_aware`` ``WindowPolicy`` ordering), and
   :class:`EnergyAware` (consolidate-and-power-gate idle slices, λ_energy
   per profile);
@@ -46,15 +53,26 @@ Integration knobs: ``SimConfig.repartition`` / ``simulate(...)`` in
 """
 from __future__ import annotations
 
+import math
+import re
 from dataclasses import dataclass, field, replace
-from typing import Dict, List, Mapping, Optional, Sequence, Tuple
+from functools import lru_cache
+from types import MappingProxyType
+from typing import (Collection, Dict, List, Mapping, NamedTuple, Optional,
+                    Sequence, Tuple)
 
 from .types import SliceSpec
 
 __all__ = [
     "SliceProfile",
     "ProfileLattice",
+    "Layout",
     "RepartitionState",
+    "MigProfile",
+    "MigLayout",
+    "A100_80GB",
+    "LAYOUTS",
+    "LayoutRecord",
     "Move",
     "RepartitionContext",
     "RepartitionPolicy",
@@ -214,6 +232,59 @@ class ProfileLattice:
 
 
 # ---------------------------------------------------------------------------
+# the layout protocol
+# ---------------------------------------------------------------------------
+
+class Layout:
+    """What the coordinator and the policies ask of a slice layout.
+
+    A layout is adopted from an inventory (a classmethod ``adopt``), lists
+    its legal moves (:meth:`merges`, :meth:`splits`: deterministic, largest
+    first), checks and applies one (:meth:`validate`, :meth:`apply`),
+    instantiates a product's :class:`SliceSpec` (:meth:`spec_for`) and says
+    whether it is legal (:meth:`is_legal`).  :meth:`capacities` are its
+    profile capacities, ascending (the demand's θ-floors are read on them);
+    :meth:`snapshot` is plain data for the coordinator's layout log.
+    ``gated`` and ``idle_streak`` are the coordinator's per-slice books; a
+    layout without gating keeps ``gated`` empty.
+    """
+
+    name = "abstract"
+    gated: Mapping[str, SliceSpec] = MappingProxyType({})
+    idle_streak: Dict[str, int]
+
+    def capacities(self) -> Tuple[float, ...]:
+        raise NotImplementedError
+
+    def merges(self, live=None) -> List["Move"]:
+        raise NotImplementedError
+
+    def splits(self, live=None) -> List["Move"]:
+        raise NotImplementedError
+
+    def products(self, move: "Move") -> List[Tuple[int, float]]:
+        """``(n_chips, capacity_bytes)`` of each slice ``move`` would make."""
+        raise NotImplementedError
+
+    def validate(self, move: "Move") -> None:
+        raise NotImplementedError
+
+    def apply(self, move: "Move") -> List[str]:
+        """Apply a split or merge to the layout; returns the product ids."""
+        raise NotImplementedError
+
+    def spec_for(self, slice_id: str, *,
+                 template: Optional[SliceSpec] = None) -> SliceSpec:
+        raise NotImplementedError
+
+    def is_legal(self) -> bool:
+        raise NotImplementedError
+
+    def snapshot(self) -> tuple:
+        raise NotImplementedError
+
+
+# ---------------------------------------------------------------------------
 # buddy layout
 # ---------------------------------------------------------------------------
 
@@ -224,20 +295,23 @@ def canonical_id(offset: int, n_chips: int) -> str:
 
 
 @dataclass
-class RepartitionState:
+class RepartitionState(Layout):
     """Buddy-allocator view of the pod: slice id -> aligned chip interval.
 
     Invariants: every interval is ``(offset, n_chips)`` with pow2
     ``n_chips`` and ``offset % n_chips == 0``; live + gated intervals are
     pairwise disjoint.  Gated slices keep their interval (their chips are
     powered off, not reassigned) and their spec, so an ungate restores
-    them exactly.
+    them exactly.  ``lattice`` is the profile lattice it was adopted under.
     """
+
+    name = "buddy"
 
     pod_chips: int
     intervals: Dict[str, Tuple[int, int]] = field(default_factory=dict)
     gated: Dict[str, SliceSpec] = field(default_factory=dict)
     idle_streak: Dict[str, int] = field(default_factory=dict)
+    lattice: Optional[ProfileLattice] = None
 
     @classmethod
     def adopt(cls, specs: Sequence[SliceSpec],
@@ -249,7 +323,7 @@ class RepartitionState:
         runs adopting the same slices agree on every buddy relationship.
         """
         pod = _next_pow2(sum(max(1, s.n_chips) for s in specs))
-        state = cls(pod_chips=pod)
+        state = cls(pod_chips=pod, lattice=lattice)
         taken: List[Tuple[int, int]] = []
         for s in sorted(specs, key=lambda s: (-s.n_chips, s.slice_id)):
             n = max(1, s.n_chips)
@@ -339,6 +413,395 @@ class RepartitionState:
         self.idle_streak.pop(b, None)
         return parent, 2 * na
 
+    # -- the layout protocol ------------------------------------------------
+    def capacities(self) -> Tuple[float, ...]:
+        return tuple(sorted(p.capacity_bytes for p in self.lattice.profiles))
+
+    def merges(self, live=None) -> List["Move"]:
+        return [Move("merge", pair)
+                for pair in self.mergeable_pairs(self.lattice, live=live)]
+
+    def splits(self, live=None) -> List["Move"]:
+        """Every lattice-legal split, largest slice first (ties by id)."""
+        sids = [s for s in self.intervals if s not in self.gated
+                and (live is None or s in live)]
+        sids.sort(key=lambda s: (-self.intervals[s][1], s))
+        return [Move("split", (s,)) for s in sids
+                if self.lattice.can_split(self.intervals[s][1])]
+
+    def products(self, move: "Move") -> List[Tuple[int, float]]:
+        _, n = self.intervals[move.targets[0]]
+        m = n // 2 if move.kind == "split" else 2 * n
+        return [(m, self.lattice.profile_for(m).capacity_bytes)] * (
+            2 if move.kind == "split" else 1)
+
+    def validate(self, move: "Move") -> None:
+        for t in move.targets:
+            if t not in self.intervals:
+                raise ValueError(f"{move.kind} target {t!r} has no buddy interval")
+        if move.kind == "split":
+            _, n = self.intervals[move.targets[0]]
+            if not self.lattice.can_split(n):
+                raise ValueError(
+                    f"split of {move.targets[0]} ({n} chips) leaves the lattice")
+        elif move.kind == "merge":
+            a, b = move.targets
+            _, n = self.intervals[a]
+            if not self.lattice.can_merge(n):
+                raise ValueError(f"merge of {a}+{b} leaves the lattice")
+            if self.buddy_of(a) != b:
+                raise ValueError(f"{a} and {b} are not buddy siblings")
+
+    def apply(self, move: "Move") -> List[str]:
+        if move.kind == "split":
+            return [cid for cid, _ in self.apply_split(move.targets[0])]
+        return [self.apply_merge(*move.targets)[0]]
+
+    def spec_for(self, slice_id: str, *,
+                 template: Optional[SliceSpec] = None) -> SliceSpec:
+        return self.lattice.spec_for(slice_id, self.intervals[slice_id][1],
+                                     template=template)
+
+    def is_legal(self) -> bool:
+        spans = sorted(self.intervals.values())
+        if any(not _is_pow2(n) or off % n or off + n > self.pod_chips
+               for off, n in spans):
+            return False
+        return all(o + n <= o2 for (o, n), (o2, _) in zip(spans, spans[1:]))
+
+    def snapshot(self) -> tuple:
+        return tuple(sorted((sid, off, n)
+                            for sid, (off, n) in self.intervals.items()))
+
+
+# ---------------------------------------------------------------------------
+# MIG layout: the A100-80GB placement table
+# ---------------------------------------------------------------------------
+
+#: compute slices and memory slots of one A100-80GB
+MIG_COMPUTE = 7
+MIG_SLOTS = 8
+
+
+@dataclass(frozen=True)
+class MigProfile:
+    """One GPU-instance profile: compute slices of 7, memory slots of 8,
+    capacity, and the memory slots it may start at."""
+
+    name: str
+    compute: int
+    mem_slots: int
+    capacity_gib: float
+    starts: Tuple[int, ...]
+
+    @property
+    def capacity_bytes(self) -> float:
+        return self.capacity_gib * GB
+
+
+#: the A100-80GB supported profiles and placements (NVIDIA MIG User Guide,
+#: ``nvidia-smi mig -lgipp``), without the ``+me`` media variant; the
+#: profile names' sizes are taken as GiB
+A100_80GB: Tuple[MigProfile, ...] = (
+    MigProfile("1g.10gb", 1, 1, 10.0, (0, 1, 2, 3, 4, 5, 6)),
+    MigProfile("1g.20gb", 1, 2, 20.0, (0, 2, 4, 6)),
+    MigProfile("2g.20gb", 2, 2, 20.0, (0, 2, 4)),
+    MigProfile("3g.40gb", 3, 4, 40.0, (0, 4)),
+    MigProfile("4g.40gb", 4, 4, 40.0, (0,)),
+    MigProfile("7g.80gb", 7, 8, 80.0, (0,)),
+)
+_MIG_BY_NAME = {p.name: p for p in A100_80GB}
+#: profiles that never share a GPU although their placements are disjoint:
+#: the A100 figure of valid GPU-instance combinations (MIG User Guide,
+#: "Supported MIG Profiles") has no 4g + 3g
+A100_EXCLUDED = frozenset({frozenset({"4g.40gb", "3g.40gb"})})
+_MIG_BY_SHAPE = {(p.compute, p.capacity_gib): p for p in A100_80GB}
+
+#: one instance on one GPU: (profile name, start slot)
+Placement = Tuple[str, int]
+
+
+def _mig_slots(placement: Placement) -> range:
+    p = _MIG_BY_NAME[placement[0]]
+    return range(placement[1], placement[1] + p.mem_slots)
+
+
+def _mig_excluded(names: Collection[str]) -> bool:
+    return any(pair <= names for pair in A100_EXCLUDED)
+
+
+def mig_compute(geometry: Sequence[Placement]) -> int:
+    return sum(_MIG_BY_NAME[name].compute for name, _ in geometry)
+
+
+def mig_legal(geometry: Sequence[Placement]) -> bool:
+    """One GPU's instances are legal: each at one of its profile's legal
+    starts, no memory slot shared, at most 7 compute slices in all, and no
+    pair of :data:`A100_EXCLUDED` profiles."""
+    if _mig_excluded({name for name, _ in geometry}):
+        return False
+    used: set = set()
+    for name, start in geometry:
+        p = _MIG_BY_NAME.get(name)
+        if p is None or start not in p.starts:
+            return False
+        slots = set(_mig_slots((name, start)))
+        if slots & used:
+            return False
+        used |= slots
+    return mig_compute(geometry) <= MIG_COMPUTE
+
+
+@lru_cache(maxsize=None)
+def mig_geometries(lo: int = 0, hi: int = MIG_SLOTS
+                   ) -> Tuple[Tuple[Placement, ...], ...]:
+    """Every set of disjoint placements at legal starts inside memory slots
+    ``[lo, hi)``, the empty set included, each ordered by start; the
+    compute budget and the exclusions are the caller's (they depend on the
+    rest of the GPU)."""
+    def walk(i: int) -> List[Tuple[Placement, ...]]:
+        if i >= hi:
+            return [()]
+        out = walk(i + 1)  # slot i left free
+        for p in A100_80GB:
+            if i in p.starts and i + p.mem_slots <= hi:
+                out += [((p.name, i),) + rest for rest in walk(i + p.mem_slots)]
+        return out
+
+    return tuple(walk(lo))
+
+
+@lru_cache(maxsize=None)
+def _mig_finer(lo: int, hi: int, rest: Tuple[Placement, ...]
+               ) -> Tuple[Tuple[Placement, ...], ...]:
+    """The split geometries of an instance over slots ``[lo, hi)`` beside
+    ``rest``, the GPU's other instances: two or more instances that leave
+    the GPU legal, finest first (more instances, then more capacity, then
+    by placement)."""
+    geoms = [geo for geo in mig_geometries(lo, hi)
+             if len(geo) >= 2 and mig_legal(rest + geo)]
+    geoms.sort(key=lambda geo: (
+        -len(geo), -sum(_MIG_BY_NAME[n].capacity_gib for n, _ in geo), geo))
+    return tuple(geoms)
+
+
+def mig_profile(n_chips: int, capacity_bytes: float) -> MigProfile:
+    """The profile of ``(n_chips, capacity)``: the six pairs are unique."""
+    p = _MIG_BY_SHAPE.get((int(n_chips), round(capacity_bytes / GB, 3)))
+    if p is None:
+        raise ValueError(
+            f"no A100-80GB profile has {n_chips} compute slices and "
+            f"{capacity_bytes / GB:g} GiB (have "
+            f"{sorted((q.compute, q.capacity_gib) for q in A100_80GB)})")
+    return p
+
+
+def _mig_place(items: Sequence[Tuple[str, MigProfile]]
+               ) -> Optional[List[Tuple[str, str, int]]]:
+    """The first legal placement of ``items`` in their order, each trying its
+    starts from the lowest (depth-first), or None."""
+    if _mig_excluded({p.name for _, p in items}) or \
+            sum(p.compute for _, p in items) > MIG_COMPUTE:
+        return None
+
+    def walk(k: int, used: frozenset) -> Optional[List[Tuple[str, str, int]]]:
+        if k == len(items):
+            return []
+        sid, p = items[k]
+        for s in p.starts:
+            slots = frozenset(range(s, s + p.mem_slots))
+            if slots & used:
+                continue
+            rest = walk(k + 1, used | slots)
+            if rest is not None:
+                return [(sid, p.name, s)] + rest
+        return None
+
+    return walk(0, frozenset())
+
+
+_GPU_ID = re.compile(r"^gpu(\d+)-")
+
+
+@dataclass
+class MigLayout(Layout):
+    """The node's GPUs under the A100-80GB placement table.
+
+    ``instances`` maps a slice id to ``(gpu, profile name, start slot)``.  A
+    merge reshapes the instances whose memory slots overlap one placement
+    of a larger profile into that one instance; a split reshapes one
+    instance into a finer legal geometry of its own slots.  Products get
+    canonical ids ``gpu<g>-<profile>@<start>``, so the same instance always
+    rebuilds the same id.  No gating: there is no power table.
+    """
+
+    name = "a100-80gb"
+
+    instances: Dict[str, Tuple[int, str, int]] = field(default_factory=dict)
+    idle_streak: Dict[str, int] = field(default_factory=dict)
+
+    @classmethod
+    def adopt(cls, specs: Sequence[SliceSpec]) -> "MigLayout":
+        """Place an inventory: each ``gpu<g>-`` slice on GPU g, its profile
+        read from ``(n_chips, capacity)``; per GPU the instances go largest
+        first (ties by id), each at its lowest start that leaves the rest a
+        legal placement.  A pure function of the inventory."""
+        by_gpu: Dict[int, List[Tuple[str, MigProfile]]] = {}
+        for s in specs:
+            m = _GPU_ID.match(s.slice_id)
+            if m is None:
+                raise ValueError(f"slice {s.slice_id!r} names no GPU; the "
+                                 "a100-80gb layout needs gpu<g>- slice ids")
+            by_gpu.setdefault(int(m.group(1)), []).append(
+                (s.slice_id, mig_profile(s.n_chips, s.capacity_bytes)))
+        layout = cls()
+        for g in sorted(by_gpu):
+            items = sorted(by_gpu[g], key=lambda x: (-x[1].compute,
+                                                     -x[1].mem_slots, x[0]))
+            placed = _mig_place(items)
+            if placed is None:
+                raise ValueError(f"GPU {g}'s instances "
+                                 f"{sorted(p.name for _, p in items)} fit no "
+                                 "legal A100-80GB layout")
+            for sid, name, start in placed:
+                layout.instances[sid] = (g, name, start)
+        return layout
+
+    # -- reading ------------------------------------------------------------
+    def geometry(self, gpu: int, without: Collection[str] = ()
+                 ) -> List[Placement]:
+        return sorted((name, s) for sid, (g, name, s) in self.instances.items()
+                      if g == gpu and sid not in without)
+
+    def profile(self, slice_id: str) -> MigProfile:
+        return _MIG_BY_NAME[self.instances[slice_id][1]]
+
+    def capacities(self) -> Tuple[float, ...]:
+        return tuple(sorted({p.capacity_bytes for p in A100_80GB}))
+
+    def is_legal(self) -> bool:
+        gpus = {g for g, _, _ in self.instances.values()}
+        return all(mig_legal(self.geometry(g)) for g in gpus)
+
+    def snapshot(self) -> tuple:
+        return tuple(sorted((sid, g, name, s)
+                            for sid, (g, name, s) in self.instances.items()))
+
+    # -- moves --------------------------------------------------------------
+    def merges(self, live=None) -> List["Move"]:
+        """Every legal merge: for each GPU and each placement of a profile,
+        the instances whose slots it overlaps, when the placement holds all
+        of their slots, is larger than each of them, and leaves the GPU
+        legal.  Largest product first, then fewer compute slices, then by
+        GPU and start."""
+        found = []
+        for g in sorted({g for g, _, _ in self.instances.values()}):
+            on_gpu = [(sid, _MIG_BY_NAME[n], s)
+                      for sid, (h, n, s) in sorted(self.instances.items())
+                      if h == g]
+            for p in A100_80GB:
+                for start in p.starts:
+                    end = start + p.mem_slots
+                    over = [(sid, q, s) for sid, q, s in on_gpu
+                            if s < end and start < s + q.mem_slots]
+                    if not over or any(
+                            (live is not None and sid not in live)
+                            or s < start or s + q.mem_slots > end
+                            or q.capacity_gib >= p.capacity_gib
+                            for sid, q, s in over):
+                        continue
+                    rest = [(q.name, s) for q_sid, q, s in on_gpu
+                            if (q_sid, q, s) not in over]
+                    if not mig_legal(rest + [(p.name, start)]):
+                        continue
+                    found.append(((-p.capacity_gib, p.compute, g, start),
+                                  Move("merge", tuple(sorted(
+                                      sid for sid, _, _ in over)),
+                                      into=((p.name, start),))))
+        found.sort(key=lambda x: x[0])
+        return [m for _, m in found]
+
+    def splits(self, live=None) -> List["Move"]:
+        """Every legal split: each instance (largest first, then by GPU,
+        start and id) into each geometry of two or more instances inside its
+        slots that leaves its GPU legal, finest first (more instances, then
+        more capacity)."""
+        out = []
+        order = sorted(
+            (sid for sid in self.instances if live is None or sid in live),
+            key=lambda sid: (-self.profile(sid).capacity_gib,
+                             -self.profile(sid).compute,
+                             self.instances[sid][0], self.instances[sid][2],
+                             sid))
+        for sid in order:
+            g, _, start = self.instances[sid]
+            rest = tuple(self.geometry(g, without=(sid,)))
+            out += [Move("split", (sid,), into=geo) for geo in _mig_finer(
+                start, start + self.profile(sid).mem_slots, rest)]
+        return out
+
+    def products(self, move: "Move") -> List[Tuple[int, float]]:
+        return [(_MIG_BY_NAME[n].compute, _MIG_BY_NAME[n].capacity_bytes)
+                for n, _ in move.into]
+
+    def validate(self, move: "Move") -> None:
+        if move.kind not in ("split", "merge"):
+            raise ValueError(f"the {self.name} layout has no {move.kind} move")
+        for t in move.targets:
+            if t not in self.instances:
+                raise ValueError(f"{move.kind} target {t!r} is no instance "
+                                 f"of the {self.name} layout")
+        gpus = {self.instances[t][0] for t in move.targets}
+        if len(gpus) != 1:
+            raise ValueError(f"{move.kind} of {move.targets} spans GPUs "
+                             f"{sorted(gpus)}; a move reshapes one GPU")
+        if any(n not in _MIG_BY_NAME for n, _ in move.into):
+            raise ValueError(f"{move.kind} into {move.into}: unknown profile")
+        (g,) = gpus
+        after = self.geometry(g, without=move.targets) + list(move.into)
+        if not mig_legal(after):
+            raise ValueError(f"{move.kind} of {move.targets} into "
+                             f"{move.into} leaves GPU {g} illegal: {after}")
+        old = {s for t in move.targets
+               for s in _mig_slots(self.instances[t][1:])}
+        new = {s for pl in move.into for s in _mig_slots(pl)}
+        if move.kind == "split":
+            if len(move.targets) != 1 or len(move.into) < 2 or not new <= old:
+                raise ValueError(f"split of {move.targets} into {move.into}: "
+                                 "one instance into two or more inside it")
+        else:
+            big = max(self.profile(t).capacity_gib for t in move.targets)
+            if (len(move.into) != 1 or not old <= new
+                    or _MIG_BY_NAME[move.into[0][0]].capacity_gib <= big):
+                raise ValueError(f"merge of {move.targets} into {move.into}: "
+                                 "one larger instance over all their slots")
+
+    def apply(self, move: "Move") -> List[str]:
+        g = self.instances[move.targets[0]][0]
+        for t in move.targets:
+            del self.instances[t]
+            self.idle_streak.pop(t, None)
+        out = []
+        for name, start in move.into:
+            sid = f"gpu{g}-{name}@{start}"
+            self.instances[sid] = (g, name, start)
+            out.append(sid)
+        return out
+
+    def spec_for(self, slice_id: str, *,
+                 template: Optional[SliceSpec] = None) -> SliceSpec:
+        p = self.profile(slice_id)
+        if template is not None:
+            return replace(template, slice_id=slice_id,
+                           capacity_bytes=p.capacity_bytes, n_chips=p.compute)
+        return SliceSpec(slice_id=slice_id, capacity_bytes=p.capacity_bytes,
+                         n_chips=p.compute)
+
+
+#: the layouts a policy's ``layout`` field may name
+LAYOUTS = {"buddy": RepartitionState, "a100-80gb": MigLayout}
+
+
 
 # ---------------------------------------------------------------------------
 # fragmentation metric
@@ -371,10 +834,27 @@ def fragmentation_index(capacities: Sequence[float],
 
 @dataclass(frozen=True)
 class Move:
-    """One repartition action; ``targets`` are the consumed slice ids."""
+    """One repartition action; ``targets`` are the consumed slice ids.
+
+    ``into`` names the products where the layout cannot derive them from
+    the targets (the MIG layout: ``(profile name, start slot)`` each); the
+    buddy layout leaves it empty.
+    """
 
     kind: str  # "split" | "merge" | "gate" | "ungate"
     targets: Tuple[str, ...]
+    into: Tuple[Placement, ...] = ()
+
+
+class LayoutRecord(NamedTuple):
+    """One entry of the coordinator's layout log: the layout after a move
+    (``move`` None: as adopted) and the scheduler's live slices then, as
+    ``(slice id, n_chips, capacity_bytes)``."""
+
+    t: float
+    move: Optional[Move]
+    layout: tuple
+    live: Tuple[Tuple[str, int, float], ...]
 
 
 @dataclass(frozen=True)
@@ -385,13 +865,17 @@ class RepartitionContext:
     specs: Mapping[str, SliceSpec]  # live inventory
     busy: frozenset  # slice ids with outstanding/running work
     gated: Mapping[str, SliceSpec]
-    # pending pool: (remaining biddable work, min_capacity) per live job
+    # pending pool: (remaining biddable work, demand floor) per live job
     demand: Tuple[Tuple[float, float], ...]
     fragmentation: float
     backlog_work: float
     idle_streak: Mapping[str, int]
-    lattice: ProfileLattice
-    state: RepartitionState
+    state: Layout
+
+    @property
+    def lattice(self) -> Optional[ProfileLattice]:
+        """The buddy layout's profile lattice; None on other layouts."""
+        return getattr(self.state, "lattice", None)
 
 
 class RepartitionPolicy:
@@ -406,6 +890,8 @@ class RepartitionPolicy:
     #: when True the coordinator attaches an :class:`EnergyModel` to the
     #: scheduler so ψ_energy scores placements by profile power draw
     energy_score = False
+    #: the coordinator's layout, by its name in :data:`LAYOUTS`
+    layout = "buddy"
 
     def propose(self, ctx: RepartitionContext) -> List[Move]:
         raise NotImplementedError
@@ -432,42 +918,95 @@ class StaticInventory(RepartitionPolicy):
 class FragmentationAware(RepartitionPolicy):
     """Split/merge driven by the stranded-work fragmentation index.
 
-    Merge pressure: when more than ``merge_threshold`` of pending work is
-    stranded (its ``min_capacity`` exceeds every live slice), merge the
-    largest lattice-legal sibling pair — repeatedly, one move per tick,
-    climbing the lattice until a slice big enough exists.  Split
-    pressure: when nothing is stranded but the queue is crowded (more
-    than ``split_queue_factor`` pending jobs per live slice), split the
-    largest slice whose halves still satisfy every pending
-    ``min_capacity`` — more windows per round, no new stranding.
+    On the buddy layout (the default): merge pressure — when more than
+    ``merge_threshold`` of pending work is stranded (its demand floor
+    exceeds every live slice), merge the largest lattice-legal sibling
+    pair, one move per tick, climbing the lattice until a slice big
+    enough exists; split pressure — when nothing is stranded but the
+    queue is crowded (more than ``split_queue_factor`` pending jobs per
+    live slice), split the largest slice whose halves still satisfy every
+    pending floor.
+
+    On the MIG layout the pressures act on free capacity, Ting et al.'s
+    fragmented-but-free notion, because the auction books work ahead:
+    a job's work is committed on the round it can bid, so only work no
+    free instance holds stays pending, and an instance with outstanding
+    commitments is busy.  Merge — when more than ``merge_threshold`` of
+    pending work is *blocked* (its floor exceeds every idle instance),
+    reshape the instances of one GPU into the smallest profile that holds
+    the smallest blocked floor, preferring a GPU whose affected instances
+    are idle, ties by id; busy instances are reshaped (drained) only for
+    a stranded floor, which no live instance holds.  Split — when nothing
+    is blocked, reshape the largest instance idle for
+    ``SPLIT_IDLE_TICKS`` ticks into its finest legal geometry whose
+    products still hold every pending floor: capacity nobody waits for
+    goes back to finer instances.  ``layout`` picks the layout.
     """
 
     name = "frag"
+    #: ticks an instance stays idle before the MIG layout splits it
+    SPLIT_IDLE_TICKS = 2
     merge_threshold: float = 0.05
     split_queue_factor: float = 4.0
+    layout: str = "buddy"
+
+    def __post_init__(self):
+        if self.layout not in LAYOUTS:
+            raise ValueError(f"FragmentationAware has no layout "
+                             f"{self.layout!r} (have {sorted(LAYOUTS)})")
 
     def propose(self, ctx: RepartitionContext) -> List[Move]:
+        if self.layout != "buddy":
+            return self._reshape(ctx)
         if ctx.fragmentation > self.merge_threshold:
-            pairs = ctx.state.mergeable_pairs(ctx.lattice, live=ctx.specs)
-            if pairs:
-                return [Move("merge", pairs[0])]
-            return []
+            return ctx.state.merges(ctx.specs)[:1]
         if not ctx.demand or ctx.fragmentation > 0.0:
             return []
         n_live = len(ctx.specs)
         if len(ctx.demand) <= self.split_queue_factor * max(1, n_live):
             return []
-        max_mc = max(mc for _, mc in ctx.demand)
-        best = None
-        for sid in sorted(ctx.specs, key=lambda s: (-ctx.specs[s].n_chips, s)):
-            n = ctx.specs[sid].n_chips
-            if not ctx.lattice.can_split(n):
-                continue
-            half = ctx.lattice.profile_for(n // 2)
-            if half.capacity_bytes >= max_mc:
-                best = sid
-                break
-        return [Move("split", (best,))] if best else []
+        return self._first_split(ctx, ctx.specs)
+
+    def _first_split(self, ctx: RepartitionContext, candidates) -> List[Move]:
+        """The first split of ``candidates`` (the layout's order: largest
+        slice first, finest geometry first) whose products hold every
+        pending floor."""
+        max_mc = max((mc for _, mc in ctx.demand), default=0.0)
+        for move in ctx.state.splits(candidates):
+            if max(c for _, c in ctx.state.products(move)) >= max_mc:
+                return [move]
+        return []
+
+    def _reshape(self, ctx: RepartitionContext) -> List[Move]:
+        layout = ctx.state
+        idle = [sid for sid in ctx.specs if sid not in ctx.busy]
+        cfree = max((ctx.specs[sid].capacity_bytes for sid in idle),
+                    default=0.0)
+        blocked = fragmentation_index([cfree], ctx.demand)
+        if blocked > self.merge_threshold:
+            cmax = max((s.capacity_bytes for s in ctx.specs.values()),
+                       default=0.0)
+            need = min((mc for _, mc in ctx.demand
+                        if cfree < mc <= max(layout.capacities())),
+                       default=None)
+            if need is None:
+                return []
+            fit = [m for m in layout.merges(ctx.specs)
+                   if layout.products(m)[0][1] >= need
+                   and (need > cmax
+                        or not any(t in ctx.busy for t in m.targets))]
+
+            def key(m: Move):
+                (n, cap), = layout.products(m)
+                return (cap, n, any(t in ctx.busy for t in m.targets),
+                        m.targets)
+
+            return [min(fit, key=key)] if fit else []
+        if blocked > 0.0:
+            return []
+        rested = {sid for sid in idle
+                  if ctx.idle_streak.get(sid, 0) >= self.SPLIT_IDLE_TICKS}
+        return self._first_split(ctx, rested)
 
     def window_demand(self, ctx: RepartitionContext) -> Optional[Tuple[float, ...]]:
         return tuple(sorted({mc for _, mc in ctx.demand if mc > 0.0}))
@@ -494,6 +1033,13 @@ class EnergyAware(RepartitionPolicy):
     min_active: int = 1
     ungate_backlog: float = 50.0
     lam_energy: Optional[Tuple[Tuple[str, float], ...]] = None
+    layout: str = "buddy"
+
+    def __post_init__(self):
+        if self.layout != "buddy":
+            raise ValueError(
+                f"EnergyAware gates by profile power, and only the buddy "
+                f"lattice has a power table; layout {self.layout!r} has none")
 
     def _lam(self, profile: SliceProfile) -> float:
         if self.lam_energy:
@@ -620,16 +1166,19 @@ class MigrationPlanner:
         self.work_credited = 0.0
 
     # -- placement search ----------------------------------------------------
-    def _find_placement(self, agent, residual: float, exclude: str,
-                        now: float, activation: float):
-        """Earliest feasible (t_start, slice_id, duration) for the residual,
-        deterministic (slices scanned in sorted order, earliest gap first,
-        ties by slice id) — or None when nothing fits in the horizon."""
+    def _find_placement(self, agent, residual: float,
+                        exclude: Collection[str], now: float,
+                        activation: float):
+        """Earliest feasible (t_start, slice_id, duration) for the residual
+        on a live slice outside ``exclude`` (the dying slice, and the other
+        slices a reshape consumes), deterministic (slices scanned in sorted
+        order, earliest gap first, ties by slice id) — or None when nothing
+        fits in the horizon."""
         sched = self.scheduler
         cfg = self.config
         best = None
         for sid in sorted(sched.slices):
-            if sid == exclude:
+            if sid in exclude:
                 continue
             tl = sched.slices[sid]
             spec = tl.spec
@@ -655,10 +1204,12 @@ class MigrationPlanner:
         return best
 
     # -- the ladder ----------------------------------------------------------
-    def evacuate(self, slice_id: str, now: float, ex=None) -> Dict[str, int]:
+    def evacuate(self, slice_id: str, now: float, ex=None,
+                 exclude: Collection[str] = ()) -> Dict[str, int]:
         """Walk the ladder over everything committed to ``slice_id``, then
-        revoke the slice.  Returns per-rung counts for the caller's
-        metrics (``migrated`` / ``preempted`` / ``lost``)."""
+        revoke the slice; residuals never migrate onto ``exclude`` (the
+        other targets of the same reshape).  Returns per-rung counts for
+        the caller's metrics (``migrated`` / ``preempted`` / ``lost``)."""
         import numpy as np
 
         from .negotiation.messages import build_migration_feedback
@@ -714,7 +1265,7 @@ class MigrationPlanner:
             # rung 1: migrate the residual to a surviving slice
             if budget > 0 and agent is not None and residual > 1e-9:
                 placed = self._find_placement(
-                    agent, residual, slice_id, now, activation)
+                    agent, residual, {slice_id, *exclude}, now, activation)
                 if placed is not None:
                     t0, sid2, need = placed
                     new_v = sched.migrate_commitment(
@@ -775,21 +1326,35 @@ class MigrationPlanner:
 class RepartitionCoordinator:
     """Owns the layout state and executes policy moves between rounds.
 
-    Drain-first protocol: a move whose target slices still have
+    The layout (``state``) is the one the policy's ``layout`` names: the
+    buddy layout over a profile lattice (default) or the A100-80GB MIG
+    table.  Drain-first protocol: a move whose target slices still have
     outstanding commitments (or a variant running/queued in the
     execution plumbing) waits, re-checked every tick, up to
-    ``drain_grace`` ticks; past that the targets are revoked —
-    ``fail_running`` + ``revoke_slice`` + ``drop_pending``, the exact
-    slice-failure path, with commit-log ``lost`` rows and
-    ``LOSS_SLICE_FAILED`` feedback.  Merged-away and gated ids retire
-    their dead-window entries so canonical-id rebirth starts clean.
+    ``drain_grace`` ticks; past that the targets are drained by force —
+    through the ``migration`` planner's ladder when there is one, whose
+    residuals may land on the reshape's own products but never on the
+    other targets, else ``fail_running`` + ``revoke_slice`` +
+    ``drop_pending``, the exact slice-failure path, with commit-log
+    ``lost`` rows and ``LOSS_SLICE_FAILED`` feedback.  Merged-away and
+    gated ids retire their dead-window entries so canonical-id rebirth
+    starts clean.
+
+    Counters: executed moves by kind (``n_splits``, ``n_merges``,
+    ``n_gates``, ``n_ungates``), forced drains (``n_forced``), the
+    stranded share of pending work at each tick (``frag_trace``, the first
+    ``MAX_TRACE`` ticks), and ``layout_log``: the layout after adoption and
+    after every executed move with the scheduler's live slices then
+    (:class:`LayoutRecord`, the first ``MAX_LAYOUT_LOG`` records).  A full
+    log takes no more entries; its readers see that by its length.
 
     Everything here is picklable plain data; the coordinator is included
     in simulator/service crash checkpoints next to the scheduler it
     references (one combined pickle graph, preserving identity).
     """
 
-    MAX_TRACE = 4096
+    MAX_TRACE = 1 << 16
+    MAX_LAYOUT_LOG = 4096
     # class-level fallback so coordinators restored from pre-migration
     # checkpoints (plain __dict__ pickling) still resolve the attribute
     migration = None
@@ -804,8 +1369,14 @@ class RepartitionCoordinator:
         # fail_running + revoke_slice + drop_pending lossy path)
         self.migration = migration
         specs = [tl.spec for tl in scheduler.slices.values()]
-        self.lattice = lattice if lattice is not None else ProfileLattice.infer(specs)
-        self.state = RepartitionState.adopt(specs, self.lattice)
+        self.state: Layout
+        if policy.layout == "buddy":
+            self.lattice = (lattice if lattice is not None
+                            else ProfileLattice.infer(specs))
+            self.state = RepartitionState.adopt(specs, self.lattice)
+        else:
+            self.lattice = None
+            self.state = LAYOUTS[policy.layout].adopt(specs)
         self.drain_grace = int(drain_grace)
         # moves waiting for their targets to drain: [(move, ticks_waited)]
         self.draining: List[Tuple[Move, int]] = []
@@ -816,6 +1387,8 @@ class RepartitionCoordinator:
         self.n_forced = 0  # drains that ended in revocation
         self.energy_joules = 0.0
         self.frag_trace: List[Tuple[float, float]] = []
+        self.layout_log: List[LayoutRecord] = []
+        self._log_layout(0.0, None)
         self._last_tick: Optional[float] = None
         if self.policy.energy_score:
             self._attach_energy_model()
@@ -833,7 +1406,10 @@ class RepartitionCoordinator:
 
     def _account_energy(self, now: float, busy: frozenset) -> None:
         """Tick-sampled energy proxy: busy slices draw profile power, idle
-        live slices draw idle power, gated slices draw nothing."""
+        live slices draw idle power, gated slices draw nothing.  Only the
+        buddy lattice has power figures; other layouts account nothing."""
+        if self.lattice is None:
+            return
         if self._last_tick is not None:
             dt = now - self._last_tick
             if dt > 0:
@@ -854,11 +1430,24 @@ class RepartitionCoordinator:
             busy.update(v.slice_id for v in ex.pending)
         return frozenset(busy)
 
+    def _floor(self, agent, caps: Tuple[float, ...]) -> float:
+        """A job's demand floor: the smallest profile capacity on which it
+        is θ-safe, with ``min_capacity`` as a lower bound.  A job safe on
+        the smallest profile keeps its ``min_capacity`` (every slice holds
+        it); one safe on none gets ``inf``.  ``is_safe_on`` memoizes each
+        capacity per agent."""
+        mc = agent.spec.min_capacity
+        if not caps or agent.is_safe_on(caps[0]):
+            return mc
+        safe = next((c for c in caps[1:] if agent.is_safe_on(c)), math.inf)
+        return max(mc, safe)
+
     def _context(self, now: float, busy: frozenset) -> RepartitionContext:
         sched = self.scheduler
         specs = {sid: tl.spec for sid, tl in sched.slices.items()}
+        caps = self.state.capacities()
         demand = tuple(
-            (a.biddable_work, a.spec.min_capacity)
+            (a.biddable_work, self._floor(a, caps))
             for _, a in sorted(sched.agents.items())
             if a.biddable_work > 0.0)
         frag = fragmentation_index(
@@ -872,8 +1461,7 @@ class RepartitionCoordinator:
             now=now, specs=specs, busy=busy, gated=dict(self.state.gated),
             demand=demand, fragmentation=frag,
             backlog_work=sum(w for w, _ in demand),
-            idle_streak=dict(self.state.idle_streak),
-            lattice=self.lattice, state=self.state)
+            idle_streak=dict(self.state.idle_streak), state=self.state)
 
     # -- the tick -----------------------------------------------------------
     def tick(self, now: float, ex=None) -> List[Move]:
@@ -894,9 +1482,18 @@ class RepartitionCoordinator:
         for move, waited in queued + [(m, 0) for m in proposed]:
             if self._execute(move, now, ex, busy, waited):
                 executed.append(move)
+                self._log_layout(now, move)
         if executed and self.policy.energy_score:
             self._attach_energy_model()
         return executed
+
+    def _log_layout(self, now: float, move: Optional[Move]) -> None:
+        if len(self.layout_log) < self.MAX_LAYOUT_LOG:
+            live = tuple(sorted(
+                (sid, tl.spec.n_chips, tl.spec.capacity_bytes)
+                for sid, tl in self.scheduler.slices.items()))
+            self.layout_log.append(
+                LayoutRecord(now, move, self.state.snapshot(), live))
 
     def _execute(self, move: Move, now: float, ex, busy: frozenset,
                  waited: int) -> bool:
@@ -906,25 +1503,32 @@ class RepartitionCoordinator:
                  if t in self.scheduler.slices}
         stuck = [t for t in move.targets
                  if move.kind != "ungate" and t in busy]
-        if stuck:
-            if waited < self.drain_grace:
-                self.draining.append((move, waited + 1))
-                return False
-            for sid in stuck:  # drain grace exhausted: revocation ladder
-                if self.migration is not None:
-                    self.migration.evacuate(sid, now, ex)
-                else:  # historical lossy slice-failure path
-                    if ex is not None:
-                        ex.fail_running(sid, now)
-                    self.scheduler.revoke_slice(sid, now)
-                    if ex is not None:
-                        ex.drop_pending(sid)
-                self.n_forced += 1
-        if move.kind == "split":
-            self._do_split(move.targets[0], now, specs[move.targets[0]])
-        elif move.kind == "merge":
-            self._do_merge(move.targets[0], move.targets[1], now,
-                           specs[move.targets[0]])
+        if stuck and waited < self.drain_grace:
+            self.draining.append((move, waited + 1))
+            return False
+        if move.kind in ("split", "merge"):
+            # the products join first, so a forced drain's residuals may
+            # migrate onto the reshaped instances
+            tmpl = replace(specs[move.targets[0]], speed=1.0)
+            for pid in self.state.apply(move):
+                self.scheduler.add_slice(self.state.spec_for(pid, template=tmpl))
+        for sid in stuck:  # drain grace exhausted: revocation ladder
+            if self.migration is not None:
+                self.migration.evacuate(sid, now, ex, exclude=move.targets)
+            else:  # historical lossy slice-failure path
+                if ex is not None:
+                    ex.fail_running(sid, now)
+                self.scheduler.revoke_slice(sid, now)
+                if ex is not None:
+                    ex.drop_pending(sid)
+            self.n_forced += 1
+        if move.kind in ("split", "merge"):
+            for t in move.targets:
+                self._retire(t, now)
+            if move.kind == "split":
+                self.n_splits += 1
+            else:
+                self.n_merges += 1
         elif move.kind == "gate":
             self._do_gate(move.targets[0], now, specs[move.targets[0]])
         elif move.kind == "ungate":
@@ -938,20 +1542,7 @@ class RepartitionCoordinator:
         for t in move.targets:
             if t not in pool:
                 raise ValueError(f"{move.kind} target {t!r} is not available")
-            if t not in self.state.intervals:
-                raise ValueError(f"{move.kind} target {t!r} has no buddy interval")
-        if move.kind == "split":
-            _, n = self.state.intervals[move.targets[0]]
-            if not self.lattice.can_split(n):
-                raise ValueError(
-                    f"split of {move.targets[0]} ({n} chips) leaves the lattice")
-        elif move.kind == "merge":
-            a, b = move.targets
-            _, n = self.state.intervals[a]
-            if not self.lattice.can_merge(n):
-                raise ValueError(f"merge of {a}+{b} leaves the lattice")
-            if self.state.buddy_of(a) != b:
-                raise ValueError(f"{a} and {b} are not buddy siblings")
+        self.state.validate(move)
 
     # -- move bodies (every scheduler call below bumps the state epoch, so
     # pipelined speculation against the old inventory is discarded) ---------
@@ -961,22 +1552,6 @@ class RepartitionCoordinator:
         left so nothing is lost, and force-revoked ones already broadcast
         their losses above."""
         self.scheduler.retire_slice(slice_id, now)
-
-    def _do_split(self, slice_id: str, now: float, spec: SliceSpec) -> None:
-        tmpl = replace(spec, speed=1.0)
-        self._retire(slice_id, now)
-        for cid, n in self.state.apply_split(slice_id):
-            self.scheduler.add_slice(
-                self.lattice.spec_for(cid, n, template=tmpl))
-        self.n_splits += 1
-
-    def _do_merge(self, a: str, b: str, now: float, spec: SliceSpec) -> None:
-        tmpl = replace(spec, speed=1.0)
-        self._retire(a, now)
-        self._retire(b, now)
-        pid, n = self.state.apply_merge(a, b)
-        self.scheduler.add_slice(self.lattice.spec_for(pid, n, template=tmpl))
-        self.n_merges += 1
 
     def _do_gate(self, slice_id: str, now: float, spec: SliceSpec) -> None:
         self._retire(slice_id, now)

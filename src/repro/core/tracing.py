@@ -21,6 +21,9 @@ per-agent or per-bid loop:
 ``jasda.score.wait``       the block on in-flight device scores
 ``jasda.settle.clear``     the clearing policy (WIS + conflict fixed point)
 ``jasda.settle.feedback``  the RoundFeedback build and broadcast
+``jasda.repartition``      a repartition tick between rounds (the service's
+                           ``RepartitionCoordinator.tick``: policy, drains,
+                           moves)
 =========================  ====================================================
 """
 from __future__ import annotations

@@ -289,7 +289,8 @@ class JasdaService:
         the normal validation protocol — no special flush here.
         """
         if self.repartition is not None:
-            self.repartition.tick(now, self.exec)
+            with span("repartition"):
+                self.repartition.tick(now, self.exec)
             nxt = now + (self.cfg.repartition_dt
                          if self.cfg.repartition_dt is not None
                          else self.cfg.round_dt)
