@@ -197,19 +197,25 @@ class JobAgent:
         """Steps 2–3: answer one announcement through the strategy.
 
         Returns the agent's :class:`BidBundle` (bids grouped per announced
-        window, aligned with ``announcement.windows``).  A finished or
-        fully-committed job answers with an empty bundle without invoking
-        the strategy.
+        window, aligned with ``announcement.windows``, and the chain each
+        group came from).  A finished or fully-committed job answers with
+        an empty bundle without invoking the strategy.
         """
         if self.finished or self.biddable_work <= TIME_EPS:
             groups: Sequence[Sequence[Variant]] = [
                 () for _ in announcement.windows
             ]
+            chains: Tuple[int, ...] = ()
         else:
             groups = self.strategy.bid(self, self.strategy_state, announcement)
+            # a strategy that names no chains built one per window
+            chains = getattr(groups, "chains", None)
+            if chains is None:
+                chains = tuple(range(len(groups)))
         return BidBundle(
             job_id=self.spec.job_id,
             by_window=tuple(tuple(g) for g in groups),
+            chains=chains,
         )
 
     def observe_feedback(self, feedback: RoundFeedback) -> bool:
@@ -289,12 +295,6 @@ class JobAgent:
             for k, v in feats.items()
         }
         h = sum(self.cfg.alphas.get(k, 0.0) * v for k, v in declared.items())
-        # Deterministic id: (window, chain position) — NOT a global counter.
-        # Regenerating the same bid set (e.g. after a discarded speculative
-        # round in the pipeline) must yield identical ids; uniqueness holds
-        # within a round because a job bids each window at most once.
-        vid = (f"{self.spec.job_id}/{window.slice_id}"
-               f"@{window.t_min:.9g}#{seq}")
         return Variant(
             job_id=self.spec.job_id,
             slice_id=window.slice_id,
@@ -308,12 +308,45 @@ class JobAgent:
                 "activation": self.atomizer.activation_cost,
                 "true_features": feats,  # ground truth (≠ declared if misreporting)
             },
-            variant_id=vid,
+            variant_id=f"{self._variant_id_prefix(window)}{seq}",
             # the risk bound this bid was generated under rides along so the
             # in-dispatch safety recheck can verify per-agent θ
             # (PackedRound.thetas); strategies may tighten the agent's own θ
             theta=self.cfg.theta if theta is None else theta,
         )
+
+    def _variant_id_prefix(self, window: Window) -> str:
+        """A bid's id is this prefix and its chain position: deterministic
+        per (window, chain position) — NOT a global counter.  Regenerating
+        the same bid set (e.g. after a discarded speculative round in the
+        pipeline) must yield identical ids; uniqueness holds within a round
+        because a job bids each window at most once."""
+        return f"{self.spec.job_id}/{window.slice_id}@{window.t_min:.9g}#"
+
+    def restamp_chain(self, chain: Sequence[Variant], window: Window) -> List[Variant]:
+        """The bids of ``chain``, which :meth:`make_variant` built for
+        another window of ``window``'s shape (capacity, ``t_min``,
+        duration, chips), addressed to ``window``: only the slice and the
+        ids change.  The feature dicts and the payloads are copies of the
+        originals' own, so no two bids share a mutable dict."""
+        prefix = self._variant_id_prefix(window)
+        out = []
+        for seq, v in enumerate(chain):
+            payload = dict(v.payload)
+            payload["true_features"] = dict(payload["true_features"])
+            out.append(Variant(
+                job_id=v.job_id,
+                slice_id=window.slice_id,
+                t_start=v.t_start,
+                duration=v.duration,
+                fmp=v.fmp,
+                local_utility=v.local_utility,
+                declared_features=dict(v.declared_features),
+                payload=payload,
+                variant_id=f"{prefix}{seq}",
+                theta=v.theta,
+            ))
+        return out
 
     # kept as an alias: pre-negotiation code and the frozen reference tests
     # call the historical underscore name

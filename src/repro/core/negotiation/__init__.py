@@ -13,7 +13,9 @@ Public surface:
   negotiation round (announce → bid → clear → feedback).
 * :class:`BiddingStrategy` — the backend protocol (owns variant
   generation, chunk sizing, window targeting, self-scoring and feedback
-  consumption); :func:`chunk_chain_bids` is the shared generation core.
+  consumption); :func:`chunk_chain_bids` is the shared generation core,
+  and :func:`chunk_chain_groups` its per-announcement form (one chain per
+  window shape) that the shipped backends answer through.
 * :class:`GreedyChunking` — the default; byte-identical to the historical
   ``JobAgent`` generation (pinned by a frozen-reference property test).
 * :class:`AdaptiveBidder` — online chunk-scale / window-targeting /
@@ -38,7 +40,8 @@ from .messages import (  # noqa: F401
     WindowAnnouncement,
     build_feedback,
 )
-from .base import BiddingStrategy, chunk_chain_bids  # noqa: F401
+from .base import (BiddingStrategy, ChainKnobs,  # noqa: F401
+                   chunk_chain_bids, chunk_chain_groups)
 from .greedy import GreedyChunking  # noqa: F401
 from .adaptive import AdaptiveBidder  # noqa: F401
 from .conservative import ConservativeSafety  # noqa: F401
@@ -51,7 +54,9 @@ __all__ = [
     "RoundFeedback",
     "build_feedback",
     "BiddingStrategy",
+    "ChainKnobs",
     "chunk_chain_bids",
+    "chunk_chain_groups",
     "GreedyChunking",
     "AdaptiveBidder",
     "ConservativeSafety",

@@ -37,7 +37,7 @@ from typing import Dict, List
 import numpy as np
 
 from ..types import Variant
-from .base import BiddingStrategy, chunk_chain_bids
+from .base import BiddingStrategy, ChainKnobs, chunk_chain_groups
 from .messages import LOSS_OUTSCORED, RoundFeedback, WindowAnnouncement
 
 __all__ = ["AdaptiveBidder"]
@@ -80,24 +80,18 @@ class AdaptiveBidder(BiddingStrategy):
     # -- bidding ---------------------------------------------------------------
     def bid(self, agent, state, announcement: WindowAnnouncement) -> List[List[Variant]]:
         scale = state["scale"]
-        out: List[List[Variant]] = []
-        for w in announcement.windows:
-            if self._skip(state, w.slice_id):
-                out.append([])
-                continue
-            out.append(
-                chunk_chain_bids(
-                    agent, w, announcement.now,
-                    announcement.chips_for(w.slice_id),
-                    shade=state["shade"],
-                    chunk_scale=scale,
-                    # at scale 1.0 the bids are byte-identical to
-                    # GreedyChunking; once shrunk, the variant budget buys
-                    # chain depth instead of head alternatives
-                    alternatives=scale >= 1.0,
-                )
-            )
-        return out
+        knobs = ChainKnobs(
+            shade=state["shade"],
+            chunk_scale=scale,
+            # at scale 1.0 the bids are byte-identical to GreedyChunking;
+            # once shrunk, the variant budget buys chain depth instead of
+            # head alternatives
+            alternatives=scale >= 1.0,
+        )
+        return chunk_chain_groups(agent, announcement, [
+            None if self._skip(state, w.slice_id) else knobs
+            for w in announcement.windows
+        ])
 
     def _skip(self, state, slice_id: str) -> bool:
         if state["streak"].get(slice_id, 0) < self.skip_after:

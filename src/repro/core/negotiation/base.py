@@ -22,6 +22,11 @@ Shipped backends (one module each):
   widens the θ safety margin as a function of calibration reliability ρ,
   making probabilistic safety an agent-side policy.
 
+Every shipped backend answers through :func:`chunk_chain_groups`, which
+builds one chunk chain per window shape and stamps it onto each window of
+that shape (the pod's many idle slices of one published shape announce
+windows that differ only in their slice).
+
 Replayability contract (the round pipeline relies on it): ``bid`` must be
 a pure function of ``(agent state, strategy state, announcement)`` except
 for the ``agent.n_bids`` counter, which the pipeline snapshots and rolls
@@ -33,13 +38,15 @@ bumps its state epoch and provably invalidates stale speculation.
 from __future__ import annotations
 
 import abc
-from typing import Any, List, Optional, Sequence
+from dataclasses import dataclass
+from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 from ..atomizer import chunk_candidates
 from ..types import TIME_EPS, Variant, Window
 from .messages import RoundFeedback, WindowAnnouncement
 
-__all__ = ["BiddingStrategy", "chunk_chain_bids"]
+__all__ = ["BiddingStrategy", "ChainGroups", "ChainKnobs", "chunk_chain_bids",
+           "chunk_chain_groups"]
 
 
 class BiddingStrategy(abc.ABC):
@@ -67,13 +74,42 @@ class BiddingStrategy(abc.ABC):
 
         Must align with ``announcement.windows`` (empty group = silent on
         that window) and must not mutate ``state`` (see the replayability
-        contract in the module docstring).
+        contract in the module docstring).  A :class:`ChainGroups` also
+        says which windows shared a chain (the round's audit row counts
+        them); a plain list counts one chain per window.
         """
 
     def observe(self, agent, state, feedback: RoundFeedback) -> bool:
         """Ingest one round's feedback; return True if ``state`` changed
         in a way that could alter future bids.  Default: stateless no-op."""
         return False
+
+
+@dataclass(frozen=True)
+class ChainKnobs:
+    """The strategy's knobs for one window's chunk chain (see
+    :func:`chunk_chain_bids` for each); the defaults are the historical
+    chain.  Frozen and hashable: the knobs are part of the chain's memo
+    key in :func:`chunk_chain_groups`."""
+
+    theta: Optional[float] = None
+    shade: float = 1.0
+    chunk_scale: float = 1.0
+    alternatives: bool = True
+    n_start_offsets: Optional[int] = None
+
+
+_DEFAULT_KNOBS = ChainKnobs()
+
+
+class ChainGroups(list):
+    """Bid groups aligned with the announced windows (a plain list of
+    lists) that also name the chain each group came from: ``chains[k]`` is
+    the index, in order of first use, of the chain computed for window k,
+    or -1 where none was (a window the strategy skipped).  Windows of one
+    shape share an index: their chain was computed once."""
+
+    __slots__ = ("chains",)
 
 
 def chunk_chain_bids(
@@ -122,14 +158,74 @@ def chunk_chain_bids(
       per position, preserving the chain's ≤-biddable-work invariant)
       while giving the packing freedom to dodge a rival's interval edge.
     """
+    variants = _chain(agent, window, now, n_chips, ChainKnobs(
+        theta=theta, shade=shade, chunk_scale=chunk_scale,
+        alternatives=alternatives, n_start_offsets=n_start_offsets))
+    if variants:
+        agent.n_bids += 1
+    return variants
+
+
+def chunk_chain_groups(
+    agent,
+    announcement: WindowAnnouncement,
+    knobs: Union[ChainKnobs, Sequence[Optional[ChainKnobs]]] = _DEFAULT_KNOBS,
+) -> ChainGroups:
+    """:func:`chunk_chain_bids` on every announced window, one chain per
+    window shape.
+
+    ``knobs`` applies to every window, or is a sequence aligned with the
+    windows whose None entries leave the agent silent there.  A chain
+    depends on the window only through its capacity, ``t_min``, duration
+    and chip count, and on the knobs; the agent's state is fixed for the
+    call.  So windows that agree on all of these share one chain: it is
+    built once, for the first of them, and every other gets copies of its
+    bids re-addressed to its own slice (``JobAgent.restamp_chain``).
+    The result equals calling :func:`chunk_chain_bids` window by window,
+    ``agent.n_bids`` included.  The memo lives for this call only.
+    """
+    windows = announcement.windows
+    now = announcement.now
+    if isinstance(knobs, ChainKnobs):
+        knobs = (knobs,) * len(windows)
+    out = ChainGroups()
+    chains: List[int] = []
+    memo: Dict[tuple, Tuple[int, List[Variant]]] = {}
+    for w, k in zip(windows, knobs):
+        if k is None:
+            out.append([])
+            chains.append(-1)
+            continue
+        n_chips = announcement.chips_for(w.slice_id)
+        key = (w.capacity, w.t_min, w.duration, n_chips, k)
+        hit = memo.get(key)
+        if hit is None:
+            hit = memo[key] = (len(memo), _chain(agent, w, now, n_chips, k))
+            variants = hit[1]
+        else:
+            variants = agent.restamp_chain(hit[1], w)
+        if variants:
+            agent.n_bids += 1
+        out.append(variants)
+        chains.append(hit[0])
+    out.chains = tuple(chains)
+    return out
+
+
+def _chain(agent, window: Window, now: float, n_chips: int,
+           knobs: ChainKnobs) -> List[Variant]:
+    """One window's chunk chain (see :func:`chunk_chain_bids`), without
+    the ``n_bids`` count."""
     if agent.finished or agent.biddable_work <= TIME_EPS:
         return []
     thr = agent.throughput_on(window.capacity, n_chips)
     if thr <= 0:
         return []  # condition (b) fails → silent
+    theta, shade = knobs.theta, knobs.shade
     # condition (a): probabilistic safety against this slice's capacity
     if not agent.is_safe_on(window.capacity, theta):
         return []
+    n_start_offsets = knobs.n_start_offsets
     if n_start_offsets is None:
         n_start_offsets = getattr(agent.cfg, "n_start_offsets", 1)
     n_start_offsets = max(1, int(n_start_offsets))
@@ -143,12 +239,12 @@ def chunk_chain_bids(
     while remaining > TIME_EPS and t_cursor < window.t_end - TIME_EPS and len(variants) < max_v:
         span = window.t_end - t_cursor
         ask = remaining
-        if chunk_scale < 1.0:
-            ask = min(remaining, max(remaining * chunk_scale, min_ask))
+        if knobs.chunk_scale < 1.0:
+            ask = min(remaining, max(remaining * knobs.chunk_scale, min_ask))
         plans = chunk_candidates(ask, thr, span, agent.atomizer)
         if not plans:
             break
-        offered = plans if alternatives else plans[:1]
+        offered = plans if knobs.alternatives else plans[:1]
         # emission order per position: carrier chunk, then its start-time
         # alternatives (the knob the agent explicitly asked for — they get
         # budget priority), then the smaller-chunk ladder.  With the
@@ -182,6 +278,4 @@ def chunk_chain_bids(
         largest = plans[0]
         remaining -= largest.work
         t_cursor += largest.duration
-    if variants:
-        agent.n_bids += 1
     return variants

@@ -24,7 +24,7 @@ from dataclasses import dataclass
 from typing import Dict, List
 
 from ..types import Variant
-from .base import BiddingStrategy, chunk_chain_bids
+from .base import BiddingStrategy, ChainKnobs, chunk_chain_groups
 from .messages import RoundFeedback, WindowAnnouncement
 
 __all__ = ["ConservativeSafety"]
@@ -54,13 +54,7 @@ class ConservativeSafety(BiddingStrategy):
         # byte-identity with GreedyChunking holds at ρ = 1
         if theta == agent.cfg.theta:
             theta = None
-        return [
-            chunk_chain_bids(
-                agent, w, announcement.now,
-                announcement.chips_for(w.slice_id), theta=theta,
-            )
-            for w in announcement.windows
-        ]
+        return chunk_chain_groups(agent, announcement, ChainKnobs(theta=theta))
 
     def observe(self, agent, state, feedback: RoundFeedback) -> bool:
         rho = feedback.reliability.get(agent.spec.job_id)

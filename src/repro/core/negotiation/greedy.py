@@ -14,7 +14,7 @@ from dataclasses import dataclass
 from typing import List
 
 from ..types import Variant
-from .base import BiddingStrategy, chunk_chain_bids
+from .base import BiddingStrategy, chunk_chain_groups
 from .messages import WindowAnnouncement
 
 __all__ = ["GreedyChunking"]
@@ -27,9 +27,4 @@ class GreedyChunking(BiddingStrategy):
     name = "greedy_chunking"
 
     def bid(self, agent, state, announcement: WindowAnnouncement) -> List[List[Variant]]:
-        return [
-            chunk_chain_bids(
-                agent, w, announcement.now, announcement.chips_for(w.slice_id)
-            )
-            for w in announcement.windows
-        ]
+        return chunk_chain_groups(agent, announcement)

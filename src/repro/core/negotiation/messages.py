@@ -85,6 +85,11 @@ class BidBundle:
 
     job_id: str
     by_window: Tuple[Tuple[Variant, ...], ...]
+    # ``chains[k]`` names the chunk chain that answered window k: windows
+    # with one number shared a chain computed once (-1: none was built).
+    # Empty when the strategy did not run (a finished or fully committed
+    # job).  The round's audit row counts them (IterationLog.n_bid_chains)
+    chains: Tuple[int, ...] = ()
 
     @property
     def variants(self) -> Tuple[Variant, ...]:

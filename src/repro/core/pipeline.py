@@ -155,6 +155,8 @@ class RoundPipeline:
                 agent.stats_restore(spec.stats_snap[agent.spec.job_id])
         spec.windows = [spec.windows[k] for k in kept]
         spec.bids = [[per_window[k] for k in kept] for per_window in spec.bids]
+        spec.chains = [tuple(c[k] for k in kept) if c else c
+                       for c in spec.chains]
         for agent, per_window in zip(spec.agents, spec.bids):
             # re-apply the n_bids a serial generation over the surviving
             # windows would have counted (one per window with bids)

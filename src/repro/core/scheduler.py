@@ -247,6 +247,12 @@ class IterationLog:
     # discarded before it
     prep: str = "serial"
     n_spec_discarded: int = 0
+    # bid collection's work, summed over agents: (agent, window) pairs a
+    # biddable agent's strategy answered through a chunk chain (not those
+    # it skipped), and the chains it computed for them (windows of one
+    # shape share a chain; BidBundle.chains)
+    n_bid_groups: int = 0
+    n_bid_chains: int = 0
 
 
 @dataclass
@@ -297,6 +303,9 @@ class RoundPrep:
     agents: List[JobAgent] = field(default_factory=list)
     # per-agent bid groups (read-only; group containers may be tuples)
     bids: List[Sequence[Sequence[Variant]]] = field(default_factory=list)
+    # per-agent chain of each bid group (BidBundle.chains; () = no bundle
+    # from the strategy)
+    chains: List[Tuple[int, ...]] = field(default_factory=list)
     pool: List[Variant] = field(default_factory=list)
     fit: List[Variant] = field(default_factory=list)
     win_idx: object = None  # (F,) window index per fitting bid
@@ -318,6 +327,12 @@ class RoundPrep:
     # the speculative preparations it discarded before this round
     source: str = "serial"
     n_spec_discarded: int = 0
+
+
+def _n_chains(chains: Tuple[int, ...]) -> int:
+    """Distinct chunk chains one agent computed (BidBundle.chains)."""
+    ids = set(chains)
+    return len(ids) - (-1 in ids)
 
 
 class JasdaScheduler:
@@ -690,21 +705,24 @@ class JasdaScheduler:
         )
         # bundle groups are consumed read-only (pooling, pipeline refilter
         # rebuilds outer lists) — keep the frozen tuples, no unwrap copy
-        bids, n_dropped = self._collect_bids(agents, announcement)
+        bids, chains, n_dropped = self._collect_bids(agents, announcement)
         prep = RoundPrep(
             now=now, epoch=self._epoch, windows=list(windows),
-            agents=agents, bids=bids, stats_snap=snap, n_dropped=n_dropped,
+            agents=agents, bids=bids, chains=chains, stats_snap=snap,
+            n_dropped=n_dropped,
         )
         self._finalize_prep(prep)
         return prep
 
     def _collect_bids(
         self, agents: List[JobAgent], announcement: WindowAnnouncement
-    ) -> Tuple[List[Sequence[Sequence[Variant]]], int]:
+    ) -> Tuple[List[Sequence[Sequence[Variant]]], List[Tuple[int, ...]], int]:
         """Bid collection with a deadline: faulty bidders never stall a round.
 
-        Without a fault gate this is exactly the historical comprehension
-        (one ``respond()`` per agent).  With one, each attempt first passes
+        Returns each agent's bid groups and the chain of each group (from
+        its ``BidBundle``), and the number of agents dropped.  Without a
+        fault gate this is one ``respond()`` per agent.  With one, each
+        attempt first passes
         through ``self.fault_gate(agent, now, attempt)``: a retryable
         fault (``AgentRespondError``) retries with capped exponential
         backoff up to ``config.bid_retries`` times; a non-retryable one
@@ -719,20 +737,22 @@ class JasdaScheduler:
         with span("bids"):
             gate = self.fault_gate
             if gate is None:
-                return [list(a.respond(announcement).by_window)
-                        for a in agents], 0
+                bundles = [a.respond(announcement) for a in agents]
+                return ([list(b.by_window) for b in bundles],
+                        [b.chains for b in bundles], 0)
             cfg = self.config
             empty: List[Sequence[Variant]] = [() for _ in announcement.windows]
             bids: List[Sequence[Sequence[Variant]]] = []
+            chains: List[Tuple[int, ...]] = []
             dropped = 0
             now = announcement.now
             for a in agents:
                 def _attempt(k: int, agent=a):
                     gate(agent, now, k)
-                    return list(agent.respond(announcement).by_window)
+                    return agent.respond(announcement)
 
                 try:
-                    bids.append(retry_with_backoff(
+                    bundle = retry_with_backoff(
                         _attempt,
                         retries=cfg.bid_retries,
                         base=cfg.bid_backoff_base,
@@ -741,11 +761,15 @@ class JasdaScheduler:
                         sleep=lambda _delay: None,
                         retryable=lambda e: isinstance(e, AgentFault)
                         and e.retryable,
-                    ))
+                    )
                 except AgentFault:
                     bids.append(list(empty))
+                    chains.append(())
                     dropped += 1
-            return bids, dropped
+                else:
+                    bids.append(list(bundle.by_window))
+                    chains.append(bundle.chains)
+            return bids, chains, dropped
 
     def _finalize_prep(self, prep: RoundPrep) -> None:
         """Pool assembly + packing + scoring dispatch for prepared bids.
@@ -900,6 +924,9 @@ class JasdaScheduler:
                     n_passes=rr.n_passes, n_reclears=rr.n_reclears,
                     n_reclears_vec=rr.n_reclears_vec,
                     prep=prep.source, n_spec_discarded=prep.n_spec_discarded,
+                    n_bid_groups=sum(len(c) - c.count(-1)
+                                     for c in prep.chains),
+                    n_bid_chains=sum(_n_chains(c) for c in prep.chains),
                 )
             )
             return rr

@@ -199,8 +199,8 @@ def _variant_sig(v: Variant):
     )
 
 
-def _check_greedy_matches_legacy(seed):
-    prod, ref, windows, chips, now = _random_case(seed)
+def _check_greedy_matches_legacy(seed, case=_random_case):
+    prod, ref, windows, chips, now = case(seed)
     got = prod.generate_variants_by_window(windows, now, chips)
     want = _ref_generate_by_window(ref, windows, now, chips)
     assert [[_variant_sig(v) for v in g] for g in got] == \
@@ -208,7 +208,7 @@ def _check_greedy_matches_legacy(seed):
         "GreedyChunking drifted from the legacy generation"
     assert prod.n_bids == ref.n_bids
     # the flat wrapper is exactly the grouped form flattened
-    prod2, ref2 = _random_case(seed)[:2]
+    prod2, ref2 = case(seed)[:2]
     flat = prod2.generate_variants_round(windows, now, chips)
     assert [_variant_sig(v) for v in flat] == \
         [_variant_sig(v) for g in want for v in g]
@@ -231,6 +231,261 @@ if HAS_HYPOTHESIS:
     @given(seed=st.integers(0, 2**31 - 1))
     def test_greedy_chunking_identity_property(seed):
         _check_greedy_matches_legacy(seed)
+
+
+def _shared_shape_case(seed, chips_mode="equal"):
+    """``_random_case``'s twin agents on windows where shapes repeat: each
+    of two or three (capacity, t_min, duration) shapes on two to four
+    slices, with equal chips per shape ("equal") or chips that differ
+    between the slices of a shape ("unequal"), three near misses of the
+    last shape, each off in one of capacity, ``t_min`` and duration, and
+    one commitment of both twins overlapping the first shape's span."""
+    prod, ref, _, _, now = _random_case(seed)
+    rng = np.random.default_rng(seed + 2)
+    windows, chips = [], {}
+    for s in range(int(rng.integers(2, 4))):
+        cap = float(rng.uniform(4.0, 16.0)) * GB
+        t0 = float(rng.uniform(0, 60))
+        dur = float(rng.uniform(8.0, 80.0))
+        n = int(rng.integers(1, 4))
+        for c in range(int(rng.integers(2, 5))):
+            sid = f"p{s}-{c}"
+            windows.append(Window(sid, cap, t0, dur))
+            chips[sid] = n + (c % 2 if chips_mode == "unequal" else 0)
+    # (a slice too small for any job's memory, a later gap, a shorter one)
+    for k, (c, t, d) in enumerate([(0.25 * GB, t0, dur), (cap, t0 + 3.0, dur),
+                                   (cap, t0, dur - 4.0)]):
+        windows.append(Window(f"near{k}", c, t, d))
+        chips[f"near{k}"] = n
+    # windows of one shape need not be adjacent in the announcement
+    windows = [windows[k] for k in rng.permutation(len(windows))]
+    first = min(windows, key=lambda w: w.slice_id)
+    busy = (first.t_min + 0.3 * first.duration,
+            first.t_min + 0.5 * first.duration)
+    for a in (prod, ref):
+        a.committed_intervals.append(busy)
+    return prod, ref, windows, chips, now
+
+
+@pytest.mark.parametrize("chips_mode", ["equal", "unequal"])
+@pytest.mark.parametrize("seed", range(8))
+def test_greedy_chunking_byte_identical_on_repeated_shapes(seed, chips_mode):
+    # windows of one shape share one chain, stamped per window: still the
+    # legacy generation, window by window
+    _check_greedy_matches_legacy(
+        seed, lambda s: _shared_shape_case(s, chips_mode))
+
+
+def _grouped_and_per_window(strategy, seed, chips_mode="unequal",
+                            n_start_offsets=1, state=None, **knobs):
+    """Twin agents with ``strategy``: the strategy's grouped answer, and
+    ``chunk_chain_bids`` window by window with ``knobs`` (None entries of
+    ``state['skip']`` slices answer empty)."""
+    from repro.core.negotiation import chunk_chain_bids
+
+    a, b, windows, chips, now = _shared_shape_case(seed, chips_mode)
+    cfg = AgentConfig(theta=a.cfg.theta, misreport=a.cfg.misreport,
+                      n_start_offsets=n_start_offsets, strategy=strategy)
+    for agent in (a, b):
+        agent.cfg = cfg
+    ann = WindowAnnouncement(now=now, windows=tuple(windows), chips=chips)
+    st_ = strategy.init_state(a) if state is None else state
+    got = strategy.bid(a, st_, ann)
+    skip = knobs.pop("skip", ())
+    want = [[] if w.slice_id in skip else
+            chunk_chain_bids(b, w, now, chips[w.slice_id], **knobs)
+            for w in windows]
+    return a, b, got, want
+
+
+@pytest.mark.parametrize("n_start_offsets", [2, 3])
+@pytest.mark.parametrize("seed", range(4))
+def test_grouped_chains_with_start_offsets_equal_per_window(seed,
+                                                            n_start_offsets):
+    a, b, got, want = _grouped_and_per_window(
+        GreedyChunking(), seed, n_start_offsets=n_start_offsets)
+    assert [[_variant_sig(v) for v in g] for g in got] == \
+        [[_variant_sig(v) for v in g] for g in want]
+    assert a.n_bids == b.n_bids
+    # the chains were shared: fewer chains than windows
+    assert len(set(got.chains)) < len(got)
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_adaptive_grouped_answer_equals_per_window(seed):
+    strat = AdaptiveBidder(skip_after=1)
+    state = strat.init_state(None)
+    # shrunk and shading, with one slice skipped as hopeless
+    state.update(scale=0.36, shade=0.7, own=0.4)
+    a, _, windows, _, _ = _shared_shape_case(seed, "unequal")
+    hot = windows[0].slice_id
+    state["streak"][hot], state["cutoff"][hot] = 3, 0.9
+    a, b, got, want = _grouped_and_per_window(
+        strat, seed, state=state, shade=0.7, chunk_scale=0.36,
+        alternatives=False, skip={hot})
+    assert [[_variant_sig(v) for v in g] for g in got] == \
+        [[_variant_sig(v) for v in g] for g in want]
+    assert a.n_bids == b.n_bids
+    assert got.chains[[w.slice_id for w in windows].index(hot)] == -1
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_conservative_grouped_answer_equals_per_window(seed):
+    strat = ConservativeSafety()
+    state = {"rho": 0.3}
+    a, b, got, want = _grouped_and_per_window(strat, seed, state=state)
+    theta = strat.effective_theta(a, state)
+    _, _, _, want = _grouped_and_per_window(strat, seed, state=state,
+                                            theta=theta)
+    assert [[_variant_sig(v) for v in g] for g in got] == \
+        [[_variant_sig(v) for v in g] for g in want]
+    assert all(v.theta == theta for g in got for v in g)
+
+
+@pytest.mark.parametrize("seed", range(3))
+def test_grouped_chains_keep_per_window_knobs_apart(seed):
+    from repro.core.negotiation import (ChainKnobs, chunk_chain_bids,
+                                        chunk_chain_groups)
+
+    a, b, windows, chips, now = _shared_shape_case(seed, "equal")
+    menu = [dict(shade=0.5), {}, None, dict(theta=0.3, chunk_scale=0.5),
+            dict(alternatives=False, n_start_offsets=2)]
+    kws = [menu[k % len(menu)] for k in range(len(windows))]
+    got = chunk_chain_groups(
+        a, WindowAnnouncement(now, tuple(windows), chips),
+        [None if kw is None else ChainKnobs(**kw) for kw in kws])
+    want = [[] if kw is None else
+            chunk_chain_bids(b, w, now, chips[w.slice_id], **kw)
+            for w, kw in zip(windows, kws)]
+    assert [[_variant_sig(v) for v in g] for g in got] == \
+        [[_variant_sig(v) for v in g] for g in want]
+    assert a.n_bids == b.n_bids
+
+
+@pytest.mark.parametrize("target", ["declared", "payload", "true_features"])
+def test_stamped_bids_share_no_mutable_dict(target):
+    prod, _, windows, chips, now = _shared_shape_case(3, "equal")
+    groups = prod.generate_variants_by_window(windows, now, chips)
+    bids = [v for g in groups for v in g]
+    assert len(bids) > len({g[0].duration for g in groups if g}), \
+        "the case must stamp one chain onto several windows"
+    before = [_variant_sig(v) for v in bids]
+    for k, v in enumerate(bids):
+        d = {"declared": v.declared_features, "payload": v.payload,
+             "true_features": v.payload["true_features"]}[target]
+        d["jct" if target != "payload" else "work"] = -1.0
+        after = [_variant_sig(x) for x in bids]
+        assert [s for j, s in enumerate(after) if j != k] == \
+            [s for j, s in enumerate(before) if j != k], \
+            f"mutating bid {k}'s {target} dict changed another bid"
+        before = after
+
+
+def test_bid_counters_on_a_hand_built_announcement():
+    # 4 windows over 2 shapes, 2 biddable agents and a finished one
+    sched = JasdaScheduler([SliceSpec("a0", 20 * GB, n_chips=4),
+                            SliceSpec("a1", 20 * GB, n_chips=4),
+                            SliceSpec("b0", 10 * GB, n_chips=2),
+                            SliceSpec("b1", 10 * GB, n_chips=2)], Policy())
+    done = _agent("J9")
+    done.record_progress(done.spec.total_work)
+    for a in (_agent("J0"), _agent("J1", work=30.0), done):
+        sched.add_job(a, 0.0)
+    windows = [Window("a0", 20 * GB, 1.0, 30.0), Window("b0", 10 * GB, 1.0, 20.0),
+               Window("a1", 20 * GB, 1.0, 30.0), Window("b1", 10 * GB, 1.0, 20.0)]
+    ann = WindowAnnouncement(now=1.0, windows=tuple(windows),
+                             chips={"a0": 4, "a1": 4, "b0": 2, "b1": 2})
+    assert sched.agents["J0"].respond(ann).chains == (0, 1, 0, 1)
+    assert done.respond(ann).chains == ()
+    sched._settle_round(sched._build_prep(1.0, windows))
+    row = sched.log[-1]
+    assert (row.n_bid_groups, row.n_bid_chains) == (8, 4)
+
+
+def test_bid_counters_leave_out_windows_a_strategy_skipped():
+    strat = AdaptiveBidder(skip_after=1)
+    sched = JasdaScheduler([SliceSpec("hot", 8 * GB), SliceSpec("ok", 8 * GB)],
+                           Policy())
+    agent = _agent("J0", strategy=strat)
+    agent.strategy_state["own"] = 0.4
+    agent.strategy_state["streak"]["hot"] = 3
+    agent.strategy_state["cutoff"]["hot"] = 0.9
+    sched.add_job(agent, 0.0)
+    windows = [Window("hot", 8 * GB, 0.0, 30.0), Window("ok", 8 * GB, 0.0, 30.0)]
+    assert agent.respond(WindowAnnouncement(0.0, tuple(windows))).chains == (-1, 0)
+    sched._settle_round(sched._build_prep(0.0, windows))
+    row = sched.log[-1]
+    assert (row.n_bid_groups, row.n_bid_chains) == (1, 1)
+
+
+def test_bid_counters_of_a_strategy_that_names_no_chains():
+    class PerWindow(BiddingStrategy):
+        name = "per_window"
+
+        def bid(self, agent, state, announcement):
+            from repro.core.negotiation import chunk_chain_bids
+
+            return [chunk_chain_bids(agent, w, announcement.now)
+                    for w in announcement.windows]
+
+    windows = (Window("a0", 8 * GB, 0.0, 30.0), Window("a1", 8 * GB, 0.0, 30.0))
+    bundle = _agent(strategy=PerWindow()).respond(
+        WindowAnnouncement(now=0.0, windows=windows))
+    assert bundle.chains == (0, 1)
+
+
+def _twin_slice_sched():
+    from repro.core import SchedulerConfig, make_workload
+
+    sched = JasdaScheduler(
+        [SliceSpec(f"s20-{k}", 20 * GB, n_chips=4) for k in range(3)]
+        + [SliceSpec(f"s10-{k}", 10 * GB, n_chips=2) for k in range(2)],
+        SchedulerConfig(score_impl="ref"))
+    for a in make_workload(18, seed=3, arrival_rate=2.0):
+        sched.add_job(a, 0.0)
+    return sched
+
+
+def _counters(sched):
+    return [(r.t, r.n_bid_groups, r.n_bid_chains) for r in sched.log]
+
+
+def test_pipelined_bid_counters_equal_serial():
+    times = [float(t) for t in range(30)]
+    serial, piped = _twin_slice_sched(), _twin_slice_sched()
+    for t in times:
+        serial.run_round(t)
+    piped.run_rounds_pipelined(times)
+    # speculation ran (used or discarded)
+    assert any(r.prep != "serial" or r.n_spec_discarded for r in piped.log)
+    assert any(r.n_bid_chains < r.n_bid_groups for r in serial.log)
+    assert _counters(piped) == _counters(serial)
+
+
+@pytest.mark.parametrize("source", ["spec_hit", "spec_filtered"])
+def test_speculated_preparation_counts_what_a_fresh_one_does(source):
+    from repro.core.pipeline import RoundPipeline
+
+    spec_s, fresh_s = _twin_slice_sched(), _twin_slice_sched()
+    pipe = RoundPipeline(spec_s)
+    spec = spec_s._prepare_round(2.0, speculative=True)
+    assert len(spec.windows) >= 2
+    if source == "spec_filtered":
+        # kill a window whose shape no other window has, so the filter
+        # drops a chain as well as groups
+        keys = [(w.capacity, w.t_min, w.duration) for w in spec.windows]
+        lone = [w for w, k in zip(spec.windows, keys) if keys.count(k) == 1]
+        dead = (lone or spec.windows)[0]
+        for s in (spec_s, fresh_s):
+            s._dead_windows.add(dead.slice_id, dead.t_min, expiry=100.0)
+    pipe._spec = spec
+    prep = pipe._take_validated(2.0)
+    assert prep is not None and prep.source == source
+    spec_s._settle_round(prep)
+    fresh_s._settle_round(fresh_s._prepare_round(2.0))
+    assert _counters(spec_s)[-1] == _counters(fresh_s)[-1]
+    row = spec_s.log[-1]
+    assert 0 < row.n_bid_chains < row.n_bid_groups
 
 
 def test_greedy_identity_holds_serial_and_pipelined():
